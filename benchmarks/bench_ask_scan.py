@@ -45,12 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
-import subprocess
-import sys
-import textwrap
 import time
-from pathlib import Path
 
 import numpy as np
 
@@ -141,70 +136,49 @@ def batch_serving(writer, n=256, frames=8):
     writer("ask_scan_unbatched_wall_ms", f"n={n}", _best_time(loop) * 1e3)
 
 
-def sharded_serving(writer, n=128, frames=16, devices=8, chunk=8):
-    """The sharded row: 1-device vs N-host-device frame-axis sharding.
+def sharded_serving(writer, n=128, frames=16, chunk=8):
+    """The sharded row: a 1-device mesh vs one over every visible device.
 
-    XLA locks the host device count at first init, so the comparison runs
-    in a subprocess with ``--xla_force_host_platform_device_count``. Both
-    mesh sizes stream the SAME chunked zoom trajectory through
+    Both mesh sizes stream the SAME chunked zoom trajectory through
     ``launch.render_service``; rows record wall time per mesh, dispatches
     per chunk (the acceptance target: exactly 1), and whether the sharded
-    canvases are bit-identical to the 1-device render.
+    canvases are bit-identical to the 1-device render. Runs in this
+    process: on a TPU host the mesh is the host's chips (a child process
+    could not reach a chip this one holds); on CPU, set
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the run
+    to shard over 8 host devices.
     """
-    root = Path(__file__).resolve().parent.parent
-    code = textwrap.dedent(f"""
-        import json, time
-        import numpy as np
-        from repro.launch.mesh import make_frames_mesh
-        from repro.launch.render_service import RenderService, zoom_bounds
-        from repro.mandelbrot import MandelbrotProblem
+    import jax
 
-        prob = MandelbrotProblem(n={n}, g=4, r=2, B=16, max_dwell={DWELL},
-                                 backend="jnp")
-        out = {{}}
-        canvases = {{}}
-        for ndev in (1, {devices}):
-            svc = RenderService(prob, mesh=make_frames_mesh(ndev),
-                                chunk_frames={chunk}, safety_factor=1e9)
-            for _ in svc.stream(zoom_bounds(svc.chunk_frames)):
-                pass  # warm the jitted sharded pipeline
-            best = None
-            for _ in range(2):
-                c, rs = svc.render(zoom_bounds({frames}))
-                best = rs if best is None or rs.wall_s < best.wall_s else best
-            canvases[ndev] = c
-            out[f"wall_ms_{{ndev}}dev"] = best.wall_s * 1e3
-            out[f"dispatches_per_chunk_{{ndev}}dev"] = best.dispatches_per_chunk
-            out[f"program_traces_{{ndev}}dev"] = best.program_traces
-            out["chunks"] = best.chunks
-        out["identical"] = int(np.array_equal(canvases[1], canvases[{devices}]))
-        print("RESULT " + json.dumps(out))
-    """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = str(root / "src")
+    from repro.launch.mesh import make_frames_mesh
+    from repro.launch.render_service import RenderService, zoom_bounds
+
+    devices = len(jax.devices())
+    prob = MandelbrotProblem(n=n, g=4, r=2, B=16, max_dwell=DWELL)
+    res = {}
+    canvases = {}
+    for ndev in sorted({1, devices}):
+        svc = RenderService(prob, mesh=make_frames_mesh(ndev),
+                            chunk_frames=chunk, safety_factor=1e9)
+        for _ in svc.stream(zoom_bounds(svc.chunk_frames)):
+            pass  # warm the jitted sharded pipeline
+        best = None
+        for _ in range(2):
+            c, rs = svc.render(zoom_bounds(frames))
+            best = rs if best is None or rs.wall_s < best.wall_s else best
+        canvases[ndev] = c
+        res[ndev] = best
     case = f"n={n} f={frames}"  # no commas: rows stay 3-column CSV
-    try:
-        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, timeout=900, env=env, cwd=root)
-    except subprocess.TimeoutExpired:
-        writer("ask_scan_sharded_error", case, "timeout after 900s")
-        return
-    if r.returncode != 0:
-        tail = " ".join(r.stderr.split())[-200:].replace(",", ";")
-        writer("ask_scan_sharded_error", case, tail)
-        return
-    res = json.loads(r.stdout.rsplit("RESULT ", 1)[1])
     writer("ask_scan_sharded_frames", case, frames)
     writer("ask_scan_sharded_devices", case, devices)
-    writer("ask_scan_sharded_wall_ms_1dev", case, res["wall_ms_1dev"])
-    writer(f"ask_scan_sharded_wall_ms_{devices}dev", case,
-           res[f"wall_ms_{devices}dev"])
+    for ndev, rs in res.items():
+        writer(f"ask_scan_sharded_wall_ms_{ndev}dev", case, rs.wall_s * 1e3)
     writer("ask_scan_sharded_dispatches_per_chunk", case,
-           res[f"dispatches_per_chunk_{devices}dev"])
+           res[devices].dispatches_per_chunk)
     writer("ask_scan_sharded_program_traces", case,
-           res[f"program_traces_{devices}dev"])
-    writer("ask_scan_sharded_identical", case, res["identical"])
+           res[devices].program_traces)
+    writer("ask_scan_sharded_identical", case,
+           int(np.array_equal(canvases[1], canvases[devices])))
 
 
 def planner_batch(writer, n=512, dwell=256, n_sparse=8, n_dense=4):
@@ -276,73 +250,44 @@ def pipelined_serving(writer, n=256, dwell=128, frames=64, chunk=8,
     disk or network without competing for the CPU cores XLA computes
     on). The pipelined wall time must land measurably below the sync
     path's summed per-chunk (compute + host-copy) cost, rs.busy_s.
-
-    Runs in a subprocess: the measurement needs a pristine XLA client
-    (background async execution), which earlier in-process suites and
-    their child processes can perturb on small CI hosts.
     """
-    root = Path(__file__).resolve().parent.parent
-    code = textwrap.dedent(f"""
-        import json, time
-        import numpy as np
-        from repro.launch.mesh import make_frames_mesh
-        from repro.launch.render_service import RenderService, zoom_bounds
-        from repro.mandelbrot import MandelbrotProblem
+    from repro.launch.mesh import make_frames_mesh
+    from repro.launch.render_service import RenderService, zoom_bounds
 
-        prob = MandelbrotProblem(n={n}, g=4, r=2, B=16, max_dwell={dwell},
-                                 backend="jnp")
-        mesh = make_frames_mesh(1)
+    prob = MandelbrotProblem(n=n, g=4, r=2, B=16, max_dwell=dwell)
+    mesh = make_frames_mesh(1)
 
-        def sink(canvases, stats):
-            time.sleep({sink_ms} / 1e3)
+    def sink(canvases, stats):
+        time.sleep(sink_ms / 1e3)
 
-        out = {{}}
-        canvases = {{}}
-        for depth in (1, 2):
-            svc = RenderService(prob, mesh=mesh, chunk_frames={chunk},
-                                pipeline_depth=depth, safety_factor=2.0)
-            for _ in svc.stream(zoom_bounds(svc.chunk_frames)):
-                pass  # warm the chunk program
-            best = None
-            for _ in range(2):
-                c, rs = svc.render(zoom_bounds({frames}), sink=sink)
-                best = rs if best is None or rs.wall_s < best.wall_s else best
-            canvases[depth] = c
-            key = "sync" if depth == 1 else "pipelined"
-            out[f"{{key}}_wall_ms"] = best.wall_s * 1e3
-            out[f"{{key}}_busy_ms"] = best.busy_s * 1e3
-            out[f"{{key}}_fetch_ms"] = best.fetch_s * 1e3
-            out["chunks"] = best.chunks
-        out["identical"] = int(np.array_equal(canvases[1], canvases[2]))
-        print("RESULT " + json.dumps(out))
-    """)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(root / "src")
+    res = {}
+    canvases = {}
+    for depth in (1, 2):
+        svc = RenderService(prob, mesh=mesh, chunk_frames=chunk,
+                            pipeline_depth=depth, safety_factor=2.0)
+        for _ in svc.stream(zoom_bounds(svc.chunk_frames)):
+            pass  # warm the chunk program
+        best = None
+        for _ in range(2):
+            c, rs = svc.render(zoom_bounds(frames), sink=sink)
+            best = rs if best is None or rs.wall_s < best.wall_s else best
+        canvases[depth] = c
+        res["sync" if depth == 1 else "pipelined"] = best
+    sync, pipe = res["sync"], res["pipelined"]
     case = f"n={n} f={frames} chunk={chunk}"
-    try:
-        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, timeout=900, env=env, cwd=root)
-    except subprocess.TimeoutExpired:
-        writer("render_pipeline_error", case, "timeout after 900s")
-        return
-    if r.returncode != 0:
-        tail = " ".join(r.stderr.split())[-200:].replace(",", ";")
-        writer("render_pipeline_error", case, tail)
-        return
-    res = json.loads(r.stdout.rsplit("RESULT ", 1)[1])
-    writer("render_pipeline_chunks", case, res["chunks"])
+    writer("render_pipeline_chunks", case, pipe.chunks)
     writer("render_pipeline_sink_ms", case, sink_ms)
-    writer("render_sync_busy_ms", case, res["sync_busy_ms"])
-    writer("render_sync_wall_ms", case, res["sync_wall_ms"])
-    writer("render_sync_fetch_ms", case, res["sync_fetch_ms"])
-    writer("render_pipelined_wall_ms", case, res["pipelined_wall_ms"])
-    writer("render_pipelined_fetch_ms", case, res["pipelined_fetch_ms"])
+    writer("render_sync_busy_ms", case, sync.busy_s * 1e3)
+    writer("render_sync_wall_ms", case, sync.wall_s * 1e3)
+    writer("render_sync_fetch_ms", case, sync.fetch_s * 1e3)
+    writer("render_pipelined_wall_ms", case, pipe.wall_s * 1e3)
+    writer("render_pipelined_fetch_ms", case, pipe.fetch_s * 1e3)
     writer("render_overlap_saved_ms", case,
-           res["sync_busy_ms"] - res["pipelined_wall_ms"])
+           (sync.busy_s - pipe.wall_s) * 1e3)
     writer("render_pipelined_speedup", case,
-           res["sync_busy_ms"] / res["pipelined_wall_ms"]
-           if res["pipelined_wall_ms"] else 0.0)
-    writer("render_pipelined_identical", case, res["identical"])
+           sync.busy_s / pipe.wall_s if pipe.wall_s else 0.0)
+    writer("render_pipelined_identical", case,
+           int(np.array_equal(canvases[1], canvases[2])))
 
 
 def feedback_serving(writer, n=256, dwell=64, frames=48, chunk=4,
@@ -800,7 +745,7 @@ def run(writer, full=False, bench_json=None, bench_json_pooled=None,
     if full:
         engines(writer, n=1024, g=4, r=2, B=32)
         batch_serving(writer, n=512, frames=16)
-        sharded_serving(writer, n=256, frames=64, devices=8, chunk=16)
+        sharded_serving(writer, n=256, frames=64, chunk=16)
         planner_batch(writer, n=512, dwell=256, n_sparse=12, n_dense=6)
         pipelined_serving(writer, n=256, dwell=128, frames=128, chunk=8)
         feedback_serving(writer, n=256, dwell=128, frames=96, chunk=8)
@@ -812,7 +757,7 @@ def run(writer, full=False, bench_json=None, bench_json_pooled=None,
     else:  # CI smoke: small n, dp recursion stays cheap
         engines(writer, n=256, g=4, r=2, B=16)
         batch_serving(writer, n=128, frames=4)
-        sharded_serving(writer, n=128, frames=16, devices=8, chunk=8)
+        sharded_serving(writer, n=128, frames=16, chunk=8)
         planner_batch(writer, n=512, dwell=128, n_sparse=8, n_dense=4)
         pipelined_serving(writer, n=256, dwell=128, frames=64, chunk=8)
         feedback_serving(writer, n=256, dwell=64, frames=48, chunk=4)

@@ -47,6 +47,10 @@ def main(argv=None) -> None:
                     help="write the pooled-tuned BENCH json (ask_scan suite)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     def writer(name, case, value):
         print(f"{name},{case},{value}", flush=True)
 

@@ -340,11 +340,20 @@ def _jitted_pooled(problem, caps: Tuple[int, ...], frames: int, mesh=None):
     if mesh is None:
         fn = jax.jit(pipeline)
     else:
-        from jax.sharding import NamedSharding, PartitionSpec
+        from jax.sharding import PartitionSpec
 
-        shards = NamedSharding(mesh, PartitionSpec(_frames_axis(mesh)))
-        fn = jax.jit(jax.vmap(pipeline), in_shardings=(shards, shards),
-                     out_shardings=(shards, shards, shards, shards))
+        def one_pool(bounds_all, live):  # this device's [1, S, ...] block
+            out = pipeline(bounds_all[0], live[0])
+            return jax.tree_util.tree_map(lambda x: x[None], out)
+
+        # shard_map, not a vmap for GSPMD to partition: each device runs
+        # the one-pool program on its own block (GSPMD partitioning of
+        # the vmapped pool crashed the TPU compiler at n >= 1024 on a
+        # 2x2 v5e mesh)
+        shards = PartitionSpec(_frames_axis(mesh))
+        fn = jax.jit(jax.shard_map(one_pool, mesh=mesh,
+                                   in_specs=(shards, shards),
+                                   out_specs=shards, check_vma=False))
     if key is not None:
         if len(_POOLED_CACHE) >= _POOLED_CACHE_MAX:
             _POOLED_CACHE.pop(next(iter(_POOLED_CACHE)))

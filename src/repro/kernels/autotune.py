@@ -230,39 +230,26 @@ def _load_file_cache(path: str) -> Optional[TuningCache]:
 
 
 def heuristic(kernel: str, *, workload=None, **sig: Any) -> Choice:
-    """Measured-once rule table used when no tuning cache entry matches.
+    """Rule table used when no tuning cache entry matches: the jnp (XLA)
+    lowering for every kernel on every platform.
 
-    Grid workloads are gather-based and always route to jnp. On TPU the
-    Pallas lowerings win (explicit VMEM blocking, no HBM round-trips
-    between levels); on CPU/GPU-via-interpret the XLA fusion wins, with a
-    mild escape-loop unroll (measured on the seed workloads: unroll=2
-    shaves ~15% off the XLA CPU while-loop, deeper unrolls lose it again
-    to code bloat).
+    The Pallas kernels do not lower for the TPU at the paper's region
+    sides (their ``(side, side)`` blocks break the (8, 128) tiling, and
+    Mosaic has no ``cumsum`` for the compaction), so a cold cache never
+    routes to them; only a tuning-cache entry can. Off the TPU a mild
+    escape-loop unroll is chosen (unroll=2 shaved ~15% off the XLA CPU
+    while-loop on the seed workloads; deeper unrolls lost it again to
+    code bloat). On the TPU no schedule has been measured, so none is
+    chosen. Grid workloads have no escape loop.
     """
-    if getattr(workload, "kind", "escape") == "grid":
-        return Choice("jnp")
-    on_tpu = jax.default_backend() == "tpu"
-    if kernel == "dwell":
-        if on_tpu:
-            return Choice("pallas", (("block", (256, 256)), ("unroll", 4)))
+    if kernel not in policy_lib.KERNEL_NAMES:
+        raise ValueError(f"unknown kernel {kernel!r}")
+    escape_loop = kernel in ("dwell", "perimeter_query", "region_dwell",
+                             "region_dwell_pooled")
+    if (escape_loop and getattr(workload, "kind", "escape") != "grid"
+            and jax.default_backend() != "tpu"):
         return Choice("jnp", (("unroll", 2),))
-    if kernel in ("perimeter_query", "region_dwell", "region_dwell_pooled"):
-        if on_tpu:
-            return Choice("pallas", (("unroll", 4),))
-        return Choice("jnp", (("unroll", 2),))
-    if kernel == "olt_compact":
-        if not on_tpu:
-            return Choice("jnp")
-        # pooled cross-frame worklists overflow the single-VMEM-block cap
-        # (1 << 16, see olt_compact.py): give them the blocked schedule --
-        # ops.compact_ranks pads ragged N up to the block multiple
-        n = sig.get("n")
-        if n is not None and int(n) > (1 << 16):
-            return Choice("pallas", (("block", 4096),))
-        return Choice("pallas")
-    if kernel in ("region_fill", "region_fill_pooled", "batched_ranks"):
-        return Choice("pallas" if on_tpu else "jnp")
-    raise ValueError(f"unknown kernel {kernel!r}")
+    return Choice("jnp")
 
 
 def choose(kernel: str, *, workload=None, cache: Optional[str] = None,
@@ -474,11 +461,8 @@ def _build_runner(kernel: str, impl: str, params: Dict[str, Any], *,
                 rng.integers(0, 256, size=N), dtype=jnp.int32)
             if impl == "jnp":
                 def run():
-                    ops._pooled_scatter(
-                        canvas, rows,
-                        jnp.broadcast_to(values[:, None, None],
-                                         (N, side, side)),
-                        ne, side=side, n=n).block_until_ready()
+                    ops._pooled_fill(canvas, rows, values, ne, side=side,
+                                     n=n).block_until_ready()
             else:
                 from repro.kernels.region_fill_pooled import (
                     region_fill_pooled)

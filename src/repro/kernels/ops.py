@@ -1,17 +1,20 @@
-"""Public jitted wrappers over the Pallas kernels with jnp fallback.
+"""Public wrappers routing each kernel to its jnp or Pallas lowering.
 
 Routing is governed by ONE object: :class:`repro.kernels.policy.KernelPolicy`
 (``policy=`` on every entry point). Its backend rungs:
 
-  ``jnp``    -- the pure-jnp oracles from ref.py (also the CPU fast path:
-                interpret mode is an interpreter, so production CPU tests
-                and benchmarks default to jnp while every kernel is still
-                validated against its oracle in tests/test_kernels.py).
-  ``pallas`` -- pl.pallas_call; compiled on TPU, interpret=True elsewhere
-                (interpret executes the kernel body on CPU for validation).
+  ``jnp``    -- the pure-jnp oracles from ref.py, fused by XLA: the
+                default rung, and the one that runs on the TPU (the XLA
+                pipelines compile for v5e at the served sizes).
+  ``pallas`` -- pl.pallas_call; interpret=True off the TPU, where every
+                kernel is validated against its oracle
+                (tests/test_kernels.py). Most bodies do not lower for the
+                TPU yet at the paper's region sides (their (side, side)
+                blocks break the (8, 128) tiling; Mosaic has no cumsum),
+                so nothing routes here unless a policy names it.
   ``tuned``  -- per-dispatch choice from the autotune harness
                 (``kernels.autotune``): JSON tuning-cache winners when the
-                policy names a cache file, measured heuristics when cold.
+                policy names a cache file, the jnp heuristics when cold.
                 The choice (impl + block/unroll schedule params) is made
                 at trace time from static arguments only.
 
@@ -132,16 +135,8 @@ def region_fill(canvas, coords, values, nonempty, *, side, n,
     tile = int(params.get("tile", tile))
     scheme = params.get("scheme", scheme)
     if impl == "jnp":
-        N = coords.shape[0]
-        iy = jnp.arange(side)
-        ys = coords[:, 0:1, None] * side + iy[None, :, None]
-        xs = coords[:, 1:2, None] * side + iy[None, None, :]
-        ys = jnp.broadcast_to(ys, (N, side, side))
-        xs = jnp.broadcast_to(xs, (N, side, side))
-        # empty OLT => push indices out of range; scatter drops them
-        ys = jnp.where(nonempty.reshape(()) > 0, ys, n)
-        vals = jnp.broadcast_to(values[:, None, None], (N, side, side))
-        return canvas.at[ys.ravel(), xs.ravel()].set(vals.ravel(), mode="drop")
+        return _fill_blocks(canvas, coords[:, 0], coords[:, 1], values,
+                            nonempty, side=side)
     return _region_fill_pallas(
         canvas, coords, values, nonempty, side=side, n=n, scheme=scheme,
         tile=tile, interpret=pol.resolve_interpret())
@@ -156,19 +151,13 @@ def region_dwell(canvas, coords, nonempty, *, side, n,
                           side=side, n=n, max_dwell=max_dwell)
     unroll = int(params.get("unroll", 1))
     if impl == "jnp" or _bounds_traced(bounds):
-        N = coords.shape[0]
         interior = (ref.region_interior_dyn if _bounds_traced(bounds)
                     else ref.region_interior_ref)
         tiles = interior(
             coords, side=side, n=n, bounds=bounds, max_dwell=max_dwell,
             workload=workload, unroll=unroll)
-        iy = jnp.arange(side)
-        ys = coords[:, 0:1, None] * side + iy[None, :, None]
-        xs = coords[:, 1:2, None] * side + iy[None, None, :]
-        ys = jnp.broadcast_to(ys, (N, side, side))
-        xs = jnp.broadcast_to(xs, (N, side, side))
-        ys = jnp.where(nonempty.reshape(()) > 0, ys, n)
-        return canvas.at[ys.ravel(), xs.ravel()].set(tiles.ravel(), mode="drop")
+        return _scatter_tiles(canvas, coords[:, 0] * side,
+                              coords[:, 1] * side, tiles, nonempty)
     return _region_dwell_pallas(
         canvas, coords, nonempty, side=side, n=n, bounds=bounds,
         max_dwell=max_dwell, scheme=scheme, tile=tile,
@@ -187,20 +176,56 @@ def pooled_bounds(bounds_all, rows):
     return jnp.moveaxis(bounds_all[rows[:, 0]], -1, 0)[:, :, None, None]
 
 
+def _scatter_tiles(canvas, y0, x0, tiles, nonempty):
+    """Write [N, side, side] ``tiles`` with their top-left pixels at
+    (y0, x0): ONE scatter whose update windows are whole tiles, so the
+    index array is [N, 2] rather than one index per pixel (at n = 4096
+    the per-pixel form took the TPU compiler ~35 s per call site). An
+    empty OLT (``nonempty == 0``) pushes every origin past the canvas,
+    and out-of-range windows are dropped whole. Duplicate padding rows
+    rewrite identical values, so the result is order-independent."""
+    y0 = jnp.where(nonempty.reshape(()) > 0, y0, canvas.shape[0])
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(0, 1))
+    return jax.lax.scatter(
+        canvas, jnp.stack([y0, x0], axis=-1).astype(jnp.int32),
+        tiles.astype(canvas.dtype), dnums,
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def _fill_blocks(canvas, by, bx, values, nonempty, *, side):
+    """Constant-fill the side x side blocks at block-grid positions
+    (by, bx) with ``values``: the values scatter into a one-cell-per-block
+    grid, which then paints the canvas in ONE elementwise select. (As a
+    windowed scatter, the TPU compiler lowered the fill to a loop over
+    regions that copied the whole canvas twice per region: 25 s of a
+    31 s n = 4096, F = 8 pooled chunk on a v5e.) An empty OLT
+    (``nonempty == 0``) drops every row; duplicate padding rows rewrite
+    identical values."""
+    gh, gw = canvas.shape[0] // side, canvas.shape[1] // side
+    by = jnp.where(nonempty.reshape(()) > 0, by, gh)
+    hit = jnp.zeros((gh, gw), jnp.bool_).at[by, bx].set(True, mode="drop")
+    val = jnp.zeros((gh, gw), canvas.dtype).at[by, bx].set(
+        values.astype(canvas.dtype), mode="drop")
+    blocks = canvas.reshape(gh, side, gw, side)
+    return jnp.where(hit[:, None, :, None], val[:, None, :, None],
+                     blocks).reshape(canvas.shape)
+
+
+def _pooled_fill(canvas, rows, values, nonempty, *, side, n):
+    """``_fill_blocks`` on the tall pooled canvas [F*n, n]: frame f's
+    block rows start at f * (n // side)."""
+    return _fill_blocks(canvas, rows[:, 0] * (n // side) + rows[:, 1],
+                        rows[:, 2], values, nonempty, side=side)
+
+
 def _pooled_scatter(canvas, rows, tiles, nonempty, *, side, n):
     """Scatter per-row [side, side] tiles onto the tall pooled canvas
     [F*n, n] at row offset frame*n -- frames are disjoint bands, so ONE
-    scatter serves the whole pool. Same drop-out-of-range idiom as the
-    jnp lowering of region_fill/region_dwell (bit-identical writes)."""
-    N = rows.shape[0]
-    iy = jnp.arange(side)
-    ys = (rows[:, 0:1, None] * n + rows[:, 1:2, None] * side
-          + iy[None, :, None])
-    xs = rows[:, 2:3, None] * side + iy[None, None, :]
-    ys = jnp.broadcast_to(ys, (N, side, side))
-    xs = jnp.broadcast_to(xs, (N, side, side))
-    ys = jnp.where(nonempty.reshape(()) > 0, ys, canvas.shape[0])
-    return canvas.at[ys.ravel(), xs.ravel()].set(tiles.ravel(), mode="drop")
+    scatter serves the whole pool, as in the per-frame jnp lowering."""
+    return _scatter_tiles(canvas, rows[:, 0] * n + rows[:, 1] * side,
+                          rows[:, 2] * side, tiles, nonempty)
 
 
 def region_fill_pooled(canvas, rows, values, nonempty, *, side, n,
@@ -209,7 +234,7 @@ def region_fill_pooled(canvas, rows, values, nonempty, *, side, n,
 
     ``rows`` [N, 3] = (frame, cy, cx), duplicate-padded like the
     per-frame fill-OLT. The fill value is external (no plane math), so
-    the frame tag simply folds into the scatter row offset (jnp) or the
+    the frame tag simply folds into the block-row index (jnp) or the
     banded BlockSpec row-block index (Pallas,
     ``kernels.region_fill_pooled``). Both lowerings produce the same
     int32 writes, so the choice is pure schedule."""
@@ -217,9 +242,7 @@ def region_fill_pooled(canvas, rows, values, nonempty, *, side, n,
     F = canvas.shape[0] // n
     impl, _ = _route(pol, "region_fill_pooled", side=side, n=n, F=F)
     if impl == "jnp":
-        return _pooled_scatter(canvas, rows, jnp.broadcast_to(
-            values[:, None, None], (rows.shape[0], side, side)),
-            nonempty, side=side, n=n)
+        return _pooled_fill(canvas, rows, values, nonempty, side=side, n=n)
     return _region_fill_pooled_pallas(
         canvas, rows, values, nonempty, side=side, n=n, F=F,
         interpret=pol.resolve_interpret())

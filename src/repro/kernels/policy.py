@@ -10,13 +10,15 @@ engines), so "which lowering" is part of the SAME identity that keys
 jitted pipelines:
 
 * ``backend`` -- the lowering ladder rung:
-    ``jnp``    the pure-jnp oracles in ``ref.py`` (CPU fast path);
-    ``pallas`` the Pallas kernel bodies (compiled on TPU, interpret
-               elsewhere unless pinned);
+    ``jnp``    the pure-jnp oracles in ``ref.py``, fused by XLA (the
+               default: the lowering that compiles for the TPU);
+    ``pallas`` the Pallas kernel bodies (interpret mode off the TPU;
+               on the TPU most do not lower yet at the paper's region
+               sides -- ROADMAP speed item 2);
     ``tuned``  per-kernel measured selection: consult the autotune
                cache (``kernels.autotune``) for the winning
                (impl, block, unroll) at this call's static signature,
-               falling back to platform heuristics when cold.
+               falling back to the jnp heuristics when cold.
 * ``interpret`` -- tri-state: ``None`` auto-resolves per call site
   (interpret whenever the default JAX platform is not TPU -- the old
   sniffing, now explicit and overridable), ``True``/``False`` pins it.
@@ -116,7 +118,7 @@ class KernelPolicy:
     because they lower differently.
     """
 
-    backend: Backend = Backend.PALLAS
+    backend: Backend = Backend.JNP
     interpret: Optional[bool] = None  # None: auto (not-on-TPU)
     overrides: Tuple[Tuple[str, Tuple], ...] = ()
     tuning_cache: Optional[str] = None

@@ -118,12 +118,18 @@ def dwell_compute(cr: jax.Array, ci: jax.Array, max_dwell: int, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "bounds", "max_dwell", "workload",
-                                    "unroll"))
+                   static_argnames=("n", "max_dwell", "workload", "unroll"))
 def mandelbrot_ref(n: int, bounds=DEFAULT_BOUNDS, max_dwell: int = 512,
                    workload=None, unroll: int = 1) -> jax.Array:
     """Oracle for the exhaustive flat kernel: full n x n value image.
-    (Named for the seed workload; ``workload=`` makes it serve any.)"""
+    (Named for the seed workload; ``workload=`` makes it serve any.)
+
+    ``bounds`` is runtime data, float32 like the served path's per-frame
+    windows: one compiled program serves every window, and each pixel
+    maps to the same plane point as in the subdivision engines. Folded in
+    as compile-time constants, the window's mapping compiled to
+    different float32 roundings than the engines' (pixels at the
+    boundary then disagreed by a few dwell)."""
     ys = jax.lax.broadcasted_iota(jnp.float32, (n, n), 0)
     xs = jax.lax.broadcasted_iota(jnp.float32, (n, n), 1)
     cr, ci = map_coords(xs, ys, n, bounds)

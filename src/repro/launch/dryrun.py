@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-THE FIRST TWO LINES ABOVE MUST STAY FIRST: jax locks the device count on
-first init, and the production meshes need 512 placeholder devices. This
-module is the ONLY place that flag is set (smoke tests/benches see 1
-device).
+``main()`` asks for 512 placeholder host devices (``XLA_FLAGS``) before
+any device query: jax locks the device count on first init, and the
+production meshes need 512. Importing this module changes nothing, so
+``run_cell`` serves callers that bring their own (small) mesh.
 
 For each cell this driver:
   1. builds ShapeDtypeStruct stand-ins (configs/shapes.py -- no allocation),
@@ -25,6 +22,7 @@ bugs; the harness records them rather than crashing the sweep.
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -198,6 +196,7 @@ def main(argv=None):
     ap.add_argument("--tag", default="baseline")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args(argv)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     from repro.configs import registry
     from repro.configs.shapes import SHAPES

@@ -24,26 +24,11 @@ from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Version-proof ``compiled.cost_analysis()``.
-
-    jaxlib <= 0.4.30 returns a dict (or None); newer jaxlib returns a
-    *list* with one properties-dict per executable program. Normalize to a
-    single flat dict, summing numeric values across programs so callers can
-    keep doing ``ca.get("flops", 0.0)``.
-    """
+    """``compiled.cost_analysis()`` as a plain dict (empty when the
+    backend reports nothing), so callers can do ``ca.get("flops", 0.0)``.
+    JAX 0.9 returns one flat properties dict for the executable."""
     ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    out: Dict[str, float] = {}
-    for part in ca:
-        for k, v in dict(part).items():
-            if isinstance(v, (int, float)) and k in out:
-                out[k] += v
-            else:
-                out[k] = v
-    return out
+    return {} if ca is None else dict(ca)
 
 
 _DTYPE_BYTES = {

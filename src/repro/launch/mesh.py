@@ -13,6 +13,7 @@ reductions (optionally int8-compressed, optim/grad_compress.py).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "make_frames_mesh",
            "data_axes", "DATA_AXES", "MODEL_AXIS", "FRAMES_AXIS"]
@@ -33,7 +34,7 @@ def make_frames_mesh(num_devices: int | None = None, *,
     complement).
     """
     n = len(jax.devices()) if num_devices is None else int(num_devices)
-    return jax.make_mesh((n,), (axis_name,))
+    return _auto_mesh((n,), (axis_name,))
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -48,15 +49,23 @@ def make_production_mesh(*, multi_pod: bool = False,
         shape = (2, 16, *ms) if multi_pod else (16, *ms)
         axes = (("pod", "data", "model_a", "model_b") if multi_pod
                 else ("data", "model_a", "model_b"))
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use tiny ones, elastic restarts reshape)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _auto_mesh(tuple(shape), tuple(axes))
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding code here
+    relies on GSPMD propagation and ``with_sharding_constraint``, which
+    the ``Explicit`` default of JAX 0.9 rejects."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

@@ -26,7 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -89,10 +88,10 @@ def pipeline_forward(cfg: ArchConfig, groups, h, mesh, *,
                             jnp.zeros_like(out_buf))
         return jax.lax.psum(out_buf, stage_axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P(stage_axis), P()),  # groups sharded by stage; h repl.
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     out = fn(groups, hs)
     return out.reshape(h.shape)

@@ -1129,10 +1129,12 @@ def main(argv=None):
                          "device shard (core.pooled)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.mandelbrot import MandelbrotProblem
 
+    enable_compile_cache()
     prob = MandelbrotProblem(n=args.n, g=4, r=2, B=16,
-                             max_dwell=args.max_dwell, backend="jnp")
+                             max_dwell=args.max_dwell)
     mesh = make_frames_mesh(args.devices)
     svc = RenderService(prob, mesh=mesh, chunk_frames=args.chunk,
                         pipeline_depth=args.pipeline_depth,

@@ -78,7 +78,7 @@ class FrameProblem:
     bounds: Union[Tuple[float, float, float, float], None] = None
     scheme: str = "sbr"  # "sbr" | "mbr"  (paper Sec. 4.3)
     tile: int = 256  # MBR tile side
-    backend: str = "pallas"  # "pallas" | "jnp" | "tuned" (sugar for policy)
+    backend: str = "jnp"  # "jnp" | "pallas" | "tuned" (sugar for policy)
     workload: Union[str, WorkloadSpec] = "mandelbrot"
     policy: Union[KernelPolicy, str, None] = None
 
