@@ -129,6 +129,12 @@ class ASKStats:
     kernel_launches: int = 0  # host->device dispatches (ASK: one per level)
     region_counts: tuple = ()  # live regions entering each level
     leaf_count: int = 0
+    # host seconds from the dispatch to the stats. On the synchronous
+    # paths that is the render; on the async paths (``ShardedDispatch``,
+    # ``PooledDispatch``) it runs from enqueue to ``finalize()``, so it
+    # includes queueing behind earlier chunks and is no device time. The
+    # device time of each stage is in a profile, under the ``ask.*``
+    # scopes; the host's wait is ``ChunkStats.wait_s``.
     wall_s: float = 0.0
     overflow_dropped: int = 0  # fused/scan modes: regions beyond capacity
     olt_caps: tuple = ()  # OLT rows allocated per level (incl. leaf level)
@@ -345,49 +351,53 @@ def _build_scan_pipeline(problem: ASKProblem, caps: Sequence[int]):
             return problem.leaf_step_dyn(state, coords, valid, level=lv,
                                          extra=extra)
 
-        roots = problem.root_coords()
-        ring = olt_lib.ring_init(roots, roots_n, ring_width)
-        parity = jnp.int32(0)
-        count = jnp.int32(min(roots_n, caps[0]))
-        dropped = jnp.int32(max(roots_n - caps[0], 0))
+        # everything below that no inner stage claims is worklist
+        # bookkeeping: the level scan's control, children (with their
+        # compaction, ``ask.compact``), ring reads and writes
+        with jax.named_scope("ask.subdivide"):
+            roots = problem.root_coords()
+            ring = olt_lib.ring_init(roots, roots_n, ring_width)
+            parity = jnp.int32(0)
+            count = jnp.int32(min(roots_n, caps[0]))
+            dropped = jnp.int32(max(roots_n - caps[0], 0))
 
-        def make_branch(lv):
-            cap_in, cap_out = caps[lv], caps[lv + 1]
+            def make_branch(lv):
+                cap_in, cap_out = caps[lv], caps[lv + 1]
 
-            def branch(carry):
-                state, ring, parity, count, dropped = carry
-                coords = olt_lib.ring_read(ring, parity, cap_in)
-                valid = jnp.arange(cap_in) < count
-                state, flags = level_at(lv, state, coords, valid)
-                flags = jnp.logical_and(flags, valid)
-                children, child_count = olt_lib.subdivide_olt(
-                    coords, flags, r=r, capacity=cap_out)
-                dropped = dropped + jnp.maximum(child_count - cap_out, 0)
-                count = jnp.minimum(child_count, cap_out)
-                ring = olt_lib.ring_write(ring, parity, children)
-                return state, ring, jnp.int32(1) - parity, count, dropped
+                def branch(carry):
+                    state, ring, parity, count, dropped = carry
+                    coords = olt_lib.ring_read(ring, parity, cap_in)
+                    valid = jnp.arange(cap_in) < count
+                    state, flags = level_at(lv, state, coords, valid)
+                    flags = jnp.logical_and(flags, valid)
+                    children, child_count = olt_lib.subdivide_olt(
+                        coords, flags, r=r, capacity=cap_out)
+                    dropped = dropped + jnp.maximum(child_count - cap_out, 0)
+                    count = jnp.minimum(child_count, cap_out)
+                    ring = olt_lib.ring_write(ring, parity, children)
+                    return state, ring, jnp.int32(1) - parity, count, dropped
 
-            return branch
+                return branch
 
-        branches = [make_branch(lv) for lv in range(levels)]
+            branches = [make_branch(lv) for lv in range(levels)]
 
-        def scan_body(carry, lv):
-            entering = carry[3]  # live count entering this level
-            carry = jax.lax.switch(lv, branches, carry)
-            return carry, entering
+            def scan_body(carry, lv):
+                entering = carry[3]  # live count entering this level
+                carry = jax.lax.switch(lv, branches, carry)
+                return carry, entering
 
-        carry = (state, ring, parity, count, dropped)
-        if levels > 0:
-            carry, entering = jax.lax.scan(
-                scan_body, carry, jnp.arange(levels, dtype=jnp.int32))
-        else:
-            entering = jnp.zeros((0,), jnp.int32)
-        state, ring, parity, count, dropped = carry
+            carry = (state, ring, parity, count, dropped)
+            if levels > 0:
+                carry, entering = jax.lax.scan(
+                    scan_body, carry, jnp.arange(levels, dtype=jnp.int32))
+            else:
+                entering = jnp.zeros((0,), jnp.int32)
+            state, ring, parity, count, dropped = carry
 
-        cap_leaf = caps[levels]
-        coords = olt_lib.ring_read(ring, parity, cap_leaf)
-        valid = jnp.arange(cap_leaf) < count
-        state = leaf_at(levels, state, coords, valid)
+            cap_leaf = caps[levels]
+            coords = olt_lib.ring_read(ring, parity, cap_leaf)
+            valid = jnp.arange(cap_leaf) < count
+            state = leaf_at(levels, state, coords, valid)
         return state, entering, count, dropped
 
     return pipeline
@@ -626,17 +636,21 @@ class ShardedDispatch:
     caps: Tuple[int, ...]
     t0: float  # perf_counter at enqueue (finalize stamps wall_s from it)
 
+    def wait(self) -> None:
+        """Block until the batch's device work has ended."""
+        jax.block_until_ready(self.states)
+
     def finalize(self, *, block_until_ready: bool = True) -> Tuple[Any, ASKStats]:
         """Block on the in-flight program and assemble ``(states, stats)``.
 
         Idempotent-by-construction is NOT promised: call once per
         dispatch. Stats transfers (``entering``/``leaf``/``dropped``) force
         a device sync regardless of ``block_until_ready``, which only
-        gates the explicit wait on the canvases.
+        gates the explicit wait on the canvases (``wait``).
         """
         states = self.states
         if block_until_ready:
-            states = jax.block_until_ready(states)
+            self.wait()
         F = self.frames
         # per-device stats come back frame-sharded; gather once, then mask
         # the padded tail out of every reduction (divisible batches skip

@@ -164,8 +164,11 @@ def subdivide_olt(
     Every flagged region (cy, cx) inserts its r*r children contiguously at
     ``rank * r * r`` -- identical layout to the paper's atomic scheme, but
     via prefix sum. Returns (child_coords [capacity, 2], child_count).
+    The prefix sum runs in the ``ask.compact`` scope, which names it in
+    the compiled program and in a profile.
     """
-    ranks, count = compact_ranks(flags)
+    with jax.named_scope("ask.compact"):
+        ranks, count = compact_ranks(flags)
     R = r * r
     n = coords.shape[0]
     dy, dx = jnp.meshgrid(jnp.arange(r), jnp.arange(r), indexing="ij")

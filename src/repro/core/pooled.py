@@ -227,9 +227,10 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
         tuned tier's blocked schedule applies (ops.compact_ranks pads
         ragged lengths); problems without a kernel policy keep the plain
         jnp scan. Every lowering is exact integer math -> identical."""
-        if pol is None:
-            return olt_lib.compact_ranks(flags)
-        return ops_lib.compact_ranks(flags, policy=pol)
+        with jax.named_scope("ask.compact"):
+            if pol is None:
+                return olt_lib.compact_ranks(flags)
+            return ops_lib.compact_ranks(flags, policy=pol)
 
     def frame_sum(rows, weights):
         """Segment-sum ``weights`` by the rows' frame tags -> [F] int32.
@@ -240,7 +241,15 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
 
     def pipeline(bounds_all, live):
         state = jnp.zeros((F * n, n), dtype=problem.workload.dtype)
+        # everything below that no inner stage claims is worklist
+        # bookkeeping: roots, the level scan's control, children, ring
+        # reads and writes, per-frame counts
+        with jax.named_scope("ask.subdivide"):
+            state, entering, leaf_f, frame_dropped = level_scan(
+                state, bounds_all, live)
+        return state.reshape(F, n, n), entering, leaf_f, frame_dropped
 
+    def level_scan(state, bounds_all, live):
         # frame-major root worklist: frame f's g^2 roots, in root order,
         # before frame f+1's -- the order every per-frame scan would use
         roots = problem.root_coords()  # [g*g, 2]
@@ -250,8 +259,9 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
             [frame_ids[:, None], jnp.tile(roots, (F, 1))], axis=1)
         flags0 = live[rows0[:, 0]]
         ranks0, count0 = ranks_of(flags0)
-        rows_c, _ = olt_lib.compact_gather(rows0, flags0, caps[0],
-                                           ranks_count=(ranks0, count0))
+        with jax.named_scope("ask.compact"):
+            rows_c, _ = olt_lib.compact_gather(
+                rows0, flags0, caps[0], ranks_count=(ranks0, count0))
         root_drop = jnp.logical_and(flags0, ranks0 >= caps[0])
         frame_dropped = frame_sum(rows0, root_drop)
         count = jnp.minimum(count0, jnp.int32(caps[0]))
@@ -309,7 +319,7 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
         leaf_f = frame_sum(rows, valid)
         state = problem.pooled_leaf_step(state, rows, valid, level=levels,
                                          bounds_all=bounds_all)
-        return state.reshape(F, n, n), entering, leaf_f, frame_dropped
+        return state, entering, leaf_f, frame_dropped
 
     return pipeline
 
@@ -470,10 +480,14 @@ class PooledDispatch:
     n_dev: int
     t0: float
 
+    def wait(self) -> None:
+        """Block until the batch's device work has ended."""
+        jax.block_until_ready(self.states)
+
     def finalize(self, *, block_until_ready: bool = True) -> Tuple[Any, ASKStats]:
         states = self.states
         if block_until_ready:
-            states = jax.block_until_ready(states)
+            self.wait()
         F = self.frames
         states = states.reshape((-1,) + states.shape[2:])
         if int(states.shape[0]) != F:

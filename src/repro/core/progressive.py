@@ -102,8 +102,11 @@ def _scan_levels(problem, caps, lo, hi, carry, extra):
         return carry, entering
 
     if hi > lo:
-        return jax.lax.scan(scan_body, carry,
-                            jnp.arange(hi - lo, dtype=jnp.int32))
+        # worklist bookkeeping, as in core.ask's scan; the stages inside
+        # carry their own scopes
+        with jax.named_scope("ask.subdivide"):
+            return jax.lax.scan(scan_body, carry,
+                                jnp.arange(hi - lo, dtype=jnp.int32))
     return carry, jnp.zeros((0,), jnp.int32)
 
 

@@ -54,14 +54,16 @@ from __future__ import annotations
 
 import argparse
 import collections
+import contextlib
 import dataclasses
 import itertools
 import json
 import os
 import time
 from pathlib import Path
-from typing import Any, Iterable, Iterator, Mapping, Tuple, Union
+from typing import Any, Iterable, Iterator, Mapping, NamedTuple, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core.feedback import OccupancyEstimator
@@ -75,9 +77,13 @@ DEFAULT_FRAMES_PER_DEVICE = 4
 # flight: 2 == classic double buffering (compute k+1 behind fetch of k)
 DEFAULT_PIPELINE_DEPTH = 2
 
+# a chunk's phases in the order it meets them: each is a profiler span
+# ``repro.<phase>`` and a ``ChunkStats.<phase>_s`` field
+PHASES = ("plan", "dispatch", "wait", "stats", "copy", "retry", "observe")
+
 __all__ = ["RenderService", "RenderStats", "ChunkStats", "ChunkResult",
            "PlannedDispatch", "zoom_bounds", "DEFAULT_FRAMES_PER_DEVICE",
-           "DEFAULT_PIPELINE_DEPTH"]
+           "DEFAULT_PIPELINE_DEPTH", "PHASES"]
 
 
 class _WallClock:
@@ -94,6 +100,30 @@ class _WallClock:
 _WALL = _WallClock()
 
 
+class _Phase:
+    """One phase of a chunk's life: the profiler span ``repro.<name>``
+    tagged ``chunk=<ChunkStats.index>`` (so every span of a chunk shares
+    one id), timed by the service clock into ``seconds``. The span costs
+    something only while a profiler session records."""
+
+    __slots__ = ("_clock", "_span", "_t0", "seconds")
+
+    def __init__(self, clock, name: str, chunk: int):
+        self._clock = clock
+        self._span = jax.profiler.TraceAnnotation(f"repro.{name}",
+                                                  chunk=chunk)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._span.__enter__()
+        self._t0 = self._clock.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = self._clock.now() - self._t0
+        self._span.__exit__(*exc)
+
+
 @dataclasses.dataclass
 class ChunkStats:
     """Per-chunk timing of the streamed pipeline.
@@ -107,6 +137,16 @@ class ChunkStats:
     shrinks by the hidden amount -- comparing a pipelined run's
     ``RenderStats.wall_s`` against a synchronous run's ``busy_s`` (the
     sum of per-chunk compute + host-copy costs) measures the overlap.
+
+    The ``*_s`` phase fields split the chunk's host time; each phase is
+    also a profiler span ``repro.<phase>`` (``_Phase``). ``plan_s``: the
+    chunker (or ``dispatch_planned``) pulling and sizing the chunk;
+    ``wait_s``: blocked until the chunk's device work ends; ``stats_s``:
+    reading the scan's counters back into ``ASKStats``; ``copy_s``: the
+    canvases' device-to-host copy (feedback path); ``retry_s``: the
+    overflow re-dispatch loop (0 unless a frame overflowed);
+    ``observe_s``: folding the counts into the estimator. ``fetch_s``
+    spans ``wait_s + stats_s + copy_s + retry_s``.
     """
 
     index: int
@@ -130,6 +170,12 @@ class ChunkStats:
     cache_hits: int = 0
     cache_misses: int = 0
     cache_bytes: int = 0  # bytes resident in the tile cache afterwards
+    plan_s: float = 0.0
+    wait_s: float = 0.0
+    stats_s: float = 0.0
+    copy_s: float = 0.0
+    retry_s: float = 0.0
+    observe_s: float = 0.0
 
     @property
     def busy_s(self) -> float:
@@ -153,6 +199,22 @@ class ChunkResult:
     chunk: ChunkStats
 
 
+class _Enqueued(NamedTuple):
+    """A dispatched chunk awaiting its finalize. ``depths``, ``p`` and
+    ``caps`` are None on the uniform (non-feedback) path."""
+
+    index: int
+    key: str
+    bounds: list
+    depths: Any
+    p: Any
+    caps: Any
+    src: str
+    handle: Any  # the engine's dispatch handle (wait() / finalize())
+    dispatch_s: float
+    plan_s: float
+
+
 class PlannedDispatch:
     """Handle of one in-flight ``RenderService.dispatch_planned`` batch.
 
@@ -167,18 +229,18 @@ class PlannedDispatch:
 
     def __init__(self, service, item, tenants, tenant_feedback):
         self._service = service
-        self._item = item  # (i, key, bounds, depths, p, caps, src, d, disp_s)
+        self._item = item  # an _Enqueued
         self._tenants = tuple(tenants)
         self._tenant_feedback = bool(tenant_feedback)
         self._done = False
 
     @property
     def frames(self) -> int:
-        return len(self._item[2])
+        return len(self._item.bounds)
 
     @property
     def workload(self) -> str:
-        return self._item[1]
+        return self._item.key
 
     @property
     def tenants(self) -> tuple:
@@ -195,13 +257,8 @@ class PlannedDispatch:
             return svc._finalize_feedback(
                 self._item, in_flight=1, tenants=self._tenants,
                 tenant_feedback=self._tenant_feedback)
-        i, key, bounds, depths, p, caps, src, d, disp_s = self._item
-        t0 = svc._clock.now()
-        canvases, st = d.finalize()
-        fetch_s = svc._clock.now() - t0
-        return ChunkResult(canvases, st, ChunkStats(
-            index=i, frames=len(bounds), dispatch_s=disp_s, fetch_s=fetch_s,
-            in_flight=1, workload=key, tenants=self._tenants))
+        return svc._finalize_uniform(self._item, in_flight=1,
+                                     tenants=self._tenants)
 
 
 @dataclasses.dataclass
@@ -470,6 +527,36 @@ class RenderService:
                                pad_to=pad, **kw)
         return d, self._clock.now() - t0
 
+    def _phase(self, name: str, chunk: int) -> _Phase:
+        return _Phase(self._clock, name, chunk)
+
+    def _enqueue(self, index: int, key: str, bounds, plan_s: float, *,
+                 depths=None, p=None, caps=None, src: str = "") -> _Enqueued:
+        """Dispatch one planned chunk inside its ``repro.dispatch``
+        span; ``dispatch_s`` is ``_dispatch``'s own enqueue time."""
+        with self._phase("dispatch", index):
+            d, secs = self._dispatch(bounds, caps=caps, key=key)
+        return _Enqueued(index, key, bounds, depths, p, caps, src, d, secs,
+                         plan_s)
+
+    def _finalize_uniform(self, item: _Enqueued, in_flight: int,
+                          tenants=()) -> ChunkResult:
+        """Block on one in-flight uniform-path chunk: wait for its
+        device work, then read its counters back (the canvases stay on
+        the device)."""
+        i = item.index
+        t0 = self._clock.now()
+        with self._phase("wait", i) as wait:
+            item.handle.wait()
+        with self._phase("stats", i) as stats:
+            canvases, st = item.handle.finalize()
+        fetch_s = self._clock.now() - t0
+        return ChunkResult(canvases, st, ChunkStats(
+            index=i, frames=len(item.bounds), dispatch_s=item.dispatch_s,
+            fetch_s=fetch_s, in_flight=in_flight, workload=item.key,
+            tenants=tuple(tenants), plan_s=item.plan_s,
+            wait_s=wait.seconds, stats_s=stats.seconds))
+
     def _pad_width(self, f: int) -> int:
         """Padding width of a feedback-path dispatch: the next power-of-
         two multiple of the device count, capped at ``chunk_frames``.
@@ -647,10 +734,13 @@ class RenderService:
         if buf:
             yield flush()
 
-    def _resolve_overflow(self, key, bounds, caps, canvases, st):
+    def _resolve_overflow(self, key, bounds, caps, canv, st, *, index: int):
         """Retry overflowing frames at doubled capacities until every
-        frame fits, then merge canvases/stats. Returns (canvases np,
-        merged ASKStats, frame re-dispatch count, retry ring rows).
+        frame fits, then merge canvases/stats. ``canv`` holds the chunk's
+        canvases on the host. Returns (canvases np, merged ASKStats,
+        frame re-dispatch count, retry ring rows, retry seconds); the
+        retry loop runs in the chunk's ``repro.retry`` phase, opened only
+        when a frame overflowed.
 
         The merged stats' ``olt_caps`` are the LARGEST capacities any of
         the chunk's frames ran at (the escalated vector when retries
@@ -669,61 +759,62 @@ class RenderService:
         retry_rows = 0
         cur = tuple(caps)
         pending = [j for j, o in enumerate(st.frame_overflow) if o]
-        canv = np.asarray(canvases)
         n_dev = int(self.mesh.devices.size)
-        if pending:
-            canv = np.array(canv)  # writable copy for the row merges
-            worst = worst_case_capacities(self._problems[key])
-        ran = self._pad_width(f) // n_dev  # pool width of the last dispatch
-        first = True
-        while pending:
-            if self.engine == "ask_pooled":
-                from repro.core.pooled import (escalate_pooled_capacities,
-                                               failed_pool_capacities)
+        retry = self._phase("retry", index) if pending else None
+        with retry or contextlib.nullcontext():
+            if pending:
+                canv = np.array(canv)  # writable copy for the row merges
+                worst = worst_case_capacities(self._problems[key])
+            ran = self._pad_width(f) // n_dev  # pool width, last dispatch
+            first = True
+            while pending:
+                if self.engine == "ask_pooled":
+                    from repro.core.pooled import (escalate_pooled_capacities,
+                                                   failed_pool_capacities)
 
-                nxt = self._pad_width(len(pending)) // n_dev
-                if first and self.estimator is not None:
-                    # First retry: size the ring from ONLY the pending
-                    # frames' measured chains + their own estimated P,
-                    # not a doubling of the whole chunk's shared pool.
-                    prob = self._problems[key]
-                    ps = [float(self.estimator.predict_quantized(
-                              self._depth(key, bounds[j]),
-                              workload=prob.workload))
-                          for j in pending]
-                    cur = failed_pool_capacities(
-                        prob, [chains[j][0] for j in pending],
-                        leaf_counts=[chains[j][1] for j in pending],
-                        frames_per_shard=nxt, frame_ps=ps,
-                        caps_prev=cur, dispatched_per_shard=ran)
+                    nxt = self._pad_width(len(pending)) // n_dev
+                    if first and self.estimator is not None:
+                        # First retry: size the ring from ONLY the pending
+                        # frames' measured chains + their own estimated P,
+                        # not a doubling of the whole chunk's shared pool.
+                        prob = self._problems[key]
+                        ps = [float(self.estimator.predict_quantized(
+                                  self._depth(key, bounds[j]),
+                                  workload=prob.workload))
+                              for j in pending]
+                        cur = failed_pool_capacities(
+                            prob, [chains[j][0] for j in pending],
+                            leaf_counts=[chains[j][1] for j in pending],
+                            frames_per_shard=nxt, frame_ps=ps,
+                            caps_prev=cur, dispatched_per_shard=ran)
+                    else:
+                        cur = escalate_pooled_capacities(
+                            cur, worst, nxt, pending, dispatched_per_shard=ran)
+                    ran = nxt
                 else:
-                    cur = escalate_pooled_capacities(
-                        cur, worst, nxt, pending, dispatched_per_shard=ran)
-                ran = nxt
-            else:
-                cur = escalate_capacities(cur, worst, pending)
-            first = False
-            d, _ = self._dispatch([bounds[j] for j in pending], caps=cur,
-                                  key=key)
-            rc, rst = d.finalize()
-            if self.engine == "ask_pooled":
-                # shared pool: one ring of 2*max(cur) rows PER DEVICE
-                retry_rows += n_dev * 2 * max(cur)
-            else:
-                retry_rows += self._pad_width(len(pending)) * 2 * max(cur)
-            retries += len(pending)
-            launches += rst.kernel_launches
-            wall += rst.wall_s
-            rc = np.asarray(rc)
-            still = []
-            for k, j in enumerate(pending):
-                if rst.frame_overflow[k] == 0:
-                    canv[j] = rc[k]
-                    chains[j] = (rst.region_counts[k],
-                                 rst.frame_leaf_counts[k])
+                    cur = escalate_capacities(cur, worst, pending)
+                first = False
+                d, _ = self._dispatch([bounds[j] for j in pending], caps=cur,
+                                      key=key)
+                rc, rst = d.finalize()
+                if self.engine == "ask_pooled":
+                    # shared pool: one ring of 2*max(cur) rows PER DEVICE
+                    retry_rows += n_dev * 2 * max(cur)
                 else:
-                    still.append(j)
-            pending = still
+                    retry_rows += self._pad_width(len(pending)) * 2 * max(cur)
+                retries += len(pending)
+                launches += rst.kernel_launches
+                wall += rst.wall_s
+                rc = np.asarray(rc)
+                still = []
+                for k, j in enumerate(pending):
+                    if rst.frame_overflow[k] == 0:
+                        canv[j] = rc[k]
+                        chains[j] = (rst.region_counts[k],
+                                     rst.frame_leaf_counts[k])
+                    else:
+                        still.append(j)
+                pending = still
         merged = ASKStats(
             levels=max((len(c) for c, _ in chains), default=0),
             kernel_launches=launches,
@@ -735,7 +826,8 @@ class RenderService:
             frame_overflow=(0,) * f,
             frame_leaf_counts=tuple(leaf for _, leaf in chains),
         )
-        return canv, merged, retries, retry_rows
+        retry_s = retry.seconds if retry else 0.0
+        return canv, merged, retries, retry_rows, retry_s
 
     def _finalize_feedback(self, item, in_flight: int, tenants=(),
                            tenant_feedback: bool = False) -> ChunkResult:
@@ -744,27 +836,34 @@ class RenderService:
         the chunk's workload namespace -- and, for multi-tenant batches
         with ``tenant_feedback``, additionally under each frame's
         tenant namespace so per-tenant plans refine independently)."""
-        i, key, bounds, depths, p, caps, src, d, disp_s = item
+        i, key, bounds, depths, caps = (item.index, item.key, item.bounds,
+                                        item.depths, item.caps)
         t0 = self._clock.now()
-        canvases, st = d.finalize()
-        canv, merged, retries, retry_rows = self._resolve_overflow(
-            key, bounds, caps, canvases, st)
+        with self._phase("wait", i) as wait:
+            item.handle.wait()
+        with self._phase("stats", i) as stats:
+            canvases, st = item.handle.finalize()
+        with self._phase("copy", i) as copy:
+            canv = np.asarray(canvases)
+        canv, merged, retries, retry_rows, retry_s = self._resolve_overflow(
+            key, bounds, caps, canv, st, index=i)
         fetch_s = self._clock.now() - t0  # retry dispatches included
         prob = self._problems[key]
-        if self.adapt:
-            self.estimator.observe_stats(depths, merged, g=prob.g, r=prob.r,
-                                         workload=prob.workload)
-            if tenant_feedback and tenants:
-                chains = merged.frame_chains()
-                by_tenant: dict = {}
-                for j, t in enumerate(tenants):
-                    by_tenant.setdefault(t, []).append(j)
-                for t, idxs in by_tenant.items():
-                    self.estimator.observe_frames(
-                        [depths[j] for j in idxs],
-                        [chains[j] for j in idxs],
-                        g=prob.g, r=prob.r, workload=prob.workload,
-                        tenant=t)
+        with self._phase("observe", i) as observe:
+            if self.adapt:
+                self.estimator.observe_stats(depths, merged, g=prob.g,
+                                             r=prob.r, workload=prob.workload)
+                if tenant_feedback and tenants:
+                    chains = merged.frame_chains()
+                    by_tenant: dict = {}
+                    for j, t in enumerate(tenants):
+                        by_tenant.setdefault(t, []).append(j)
+                    for t, idxs in by_tenant.items():
+                        self.estimator.observe_frames(
+                            [depths[j] for j in idxs],
+                            [chains[j] for j in idxs],
+                            g=prob.g, r=prob.r, workload=prob.workload,
+                            tenant=t)
         if self.engine == "ask_pooled":
             # ONE shared ring per device shard, not one per frame
             ring = (int(self.mesh.devices.size) * 2 * max(caps)
@@ -772,10 +871,12 @@ class RenderService:
         else:
             ring = self._pad_width(len(bounds)) * 2 * max(caps) + retry_rows
         return ChunkResult(canv, merged, ChunkStats(
-            index=i, frames=len(bounds), dispatch_s=disp_s,
-            fetch_s=fetch_s, in_flight=in_flight, p_subdiv=p,
-            p_source=src, retries=retries,
-            ring_rows=ring, workload=key, tenants=tuple(tenants)))
+            index=i, frames=len(bounds), dispatch_s=item.dispatch_s,
+            fetch_s=fetch_s, in_flight=in_flight, p_subdiv=item.p,
+            p_source=item.src, retries=retries,
+            ring_rows=ring, workload=key, tenants=tuple(tenants),
+            plan_s=item.plan_s, wait_s=wait.seconds, stats_s=stats.seconds,
+            copy_s=copy.seconds, retry_s=retry_s, observe_s=observe.seconds))
 
     # -- multi-tenant front-door seam ---------------------------------------
 
@@ -850,27 +951,28 @@ class RenderService:
                 raise ValueError(
                     "mixed-workload dispatch_planned needs feedback= "
                     "(same contract as the streaming chunker)")
-            d, secs = self._dispatch(bounds, key=key)
-            item = (index, key, bounds, None, None, None, "", d, secs)
+            item = self._enqueue(index, key, bounds, 0.0)
             return PlannedDispatch(self, item, tenants, tenant_feedback)
-        est = self.estimator
-        wl = self._problems[key].workload
-        depths = [self._depth(key, b) for b in bounds]
-        t_of = (lambda j: tenants[j]) if (tenant_feedback and tenants) \
-            else (lambda j: None)
-        ps = [est.predict_quantized(d, workload=wl, tenant=t_of(j))
-              for j, d in enumerate(depths)]
-        sources = {"measured"
-                   if est.measured(d, workload=wl, tenant=t_of(j)) is not None
-                   else "prior"
-                   for j, d in enumerate(depths)}
-        src = sources.pop() if len(sources) == 1 else "mixed"
-        if self.engine == "ask_pooled":
-            caps = self._pooled_caps_for(key, ps)
-        else:
-            caps = self._caps_for(key, max(ps))
-        d, secs = self._dispatch(bounds, caps=caps, key=key)
-        item = (index, key, bounds, depths, max(ps), caps, src, d, secs)
+        with self._phase("plan", index) as plan:
+            est = self.estimator
+            wl = self._problems[key].workload
+            depths = [self._depth(key, b) for b in bounds]
+            t_of = (lambda j: tenants[j]) if (tenant_feedback and tenants) \
+                else (lambda j: None)
+            ps = [est.predict_quantized(d, workload=wl, tenant=t_of(j))
+                  for j, d in enumerate(depths)]
+            sources = {"measured"
+                       if est.measured(d, workload=wl,
+                                       tenant=t_of(j)) is not None
+                       else "prior"
+                       for j, d in enumerate(depths)}
+            src = sources.pop() if len(sources) == 1 else "mixed"
+            if self.engine == "ask_pooled":
+                caps = self._pooled_caps_for(key, ps)
+            else:
+                caps = self._caps_for(key, max(ps))
+        item = self._enqueue(index, key, bounds, plan.seconds, depths=depths,
+                             p=max(ps), caps=caps, src=src)
         return PlannedDispatch(self, item, tenants, tenant_feedback)
 
     def _stream_feedback(self, bounds_iter: Iterable) -> Iterator[ChunkResult]:
@@ -883,12 +985,14 @@ class RenderService:
 
         def enqueue() -> bool:
             nonlocal index
-            item = next(chunks, None)
+            with self._phase("plan", index) as plan:
+                item = next(chunks, None)
             if item is None:
                 return False
             key, bounds, depths, p, caps, src = item
-            d, secs = self._dispatch(bounds, caps=caps, key=key)
-            pending.append((index, key, bounds, depths, p, caps, src, d, secs))
+            pending.append(self._enqueue(index, key, bounds, plan.seconds,
+                                         depths=depths, p=p, caps=caps,
+                                         src=src))
             index += 1
             return True
 
@@ -938,38 +1042,28 @@ class RenderService:
 
         def enqueue() -> bool:
             nonlocal index
-            chunk = list(itertools.islice(it, self.chunk_frames))
+            with self._phase("plan", index) as plan:
+                chunk = list(itertools.islice(it, self.chunk_frames))
             if not chunk:
                 return False
-            d, secs = self._dispatch(chunk)
-            pending.append((index, len(chunk), d, secs))
+            pending.append(self._enqueue(index, "", chunk, plan.seconds))
             index += 1
             return True
 
         if self.pipeline_depth == 1:  # synchronous: at most one in flight
             while enqueue():
-                i, f, d, disp_s = pending.popleft()
-                t0 = self._clock.now()
-                canvases, st = d.finalize()
-                fetch_s = self._clock.now() - t0
-                yield ChunkResult(canvases, st, ChunkStats(
-                    index=i, frames=f, dispatch_s=disp_s, fetch_s=fetch_s,
-                    in_flight=1))
+                yield self._finalize_uniform(pending.popleft(), in_flight=1)
             return
 
         while len(pending) < self.pipeline_depth and enqueue():
             pass
         while pending:
             in_flight = len(pending)
-            i, f, d, disp_s = pending.popleft()
-            t0 = self._clock.now()
-            canvases, st = d.finalize()  # younger chunks compute behind this
-            fetch_s = self._clock.now() - t0
+            # younger chunks compute behind this one's finalize
+            result = self._finalize_uniform(pending.popleft(), in_flight)
             enqueue()  # refill BEFORE yielding: devices stay busy while the
             #            consumer processes this chunk
-            yield ChunkResult(canvases, st, ChunkStats(
-                index=i, frames=f, dispatch_s=disp_s, fetch_s=fetch_s,
-                in_flight=in_flight))
+            yield result
 
     def stream(self, bounds_iter: Iterable):
         """Yield (canvases [f, n, n], ASKStats) per chunk (the PR-2
@@ -1158,6 +1252,11 @@ def main(argv=None):
         print(f"feedback: retries={rs.retries} ring_rows={rs.ring_rows} "
               f"plan_signatures={rs.plan_signatures} "
               f"sources={[c.p_source for c in rs.chunk_stats]}")
+    chunks = rs.chunk_stats or (ChunkStats(0, 0, 0.0, 0.0, 0),)
+    means = {ph: 1e3 * sum(getattr(c, f"{ph}_s") for c in chunks)
+             / len(chunks) for ph in PHASES}
+    print("mean ms/chunk: "
+          + "  ".join(f"{ph}={ms:.2f}" for ph, ms in means.items()))
     return 0
 
 

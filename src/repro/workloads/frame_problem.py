@@ -15,10 +15,13 @@ Per level, ``level_step`` performs:
   T (fill homogeneous regions)   kernels/region_fill.py
   subdivide flags                for the driver's OLT step
 and ``leaf_step`` performs the last-level application work A
-(kernels/region_dwell.py). The workload spec rides into every kernel as
-a static argument, so one kernel body serves all escape-time workloads
-bit-identically to its jnp oracle; grid workloads route through the jnp
-path (see ``kernels.ops``).
+(kernels/region_dwell.py). Q, T and A run in the ``jax.named_scope``s
+``ask.query``, ``ask.fill`` and ``ask.dwell`` (the drivers add
+``ask.compact`` and ``ask.subdivide``), which name every operation of a
+stage in the compiled program and in a profile. The workload spec
+rides into every kernel as a static argument, so one kernel body serves
+all escape-time workloads bit-identically to its jnp oracle; grid
+workloads route through the jnp path (see ``kernels.ops``).
 
 ``MandelbrotProblem`` is a back-compat alias: a ``FrameProblem`` whose
 default workload is the registry's ``mandelbrot`` spec is the exact
@@ -121,24 +124,26 @@ class FrameProblem:
                    bounds=None) -> Tuple[jax.Array, jax.Array]:
         bounds = self.bounds if bounds is None else bounds
         side = self.region_side(level)
-        homog, common = ops.perimeter_query(
-            coords, side=side, n=self.n, bounds=bounds,
-            max_dwell=self.max_dwell, policy=self.policy,
-            workload=self.workload)
-        homog = jnp.logical_and(homog, valid)
+        with jax.named_scope("ask.query"):
+            homog, common = ops.perimeter_query(
+                coords, side=side, n=self.n, bounds=bounds,
+                max_dwell=self.max_dwell, policy=self.policy,
+                workload=self.workload)
+            homog = jnp.logical_and(homog, valid)
 
-        # compact fill-OLT; pad with duplicates of the first live row
-        cap = coords.shape[0]
-        (idx,) = jnp.nonzero(homog, size=cap, fill_value=0)
-        count = jnp.sum(homog.astype(jnp.int32))
-        live = jnp.arange(cap) < count
-        idx = jnp.where(live, idx, idx[0])
-        fill_coords = coords[idx]
-        fill_vals = common[idx]
-        nonempty = (count > 0).astype(jnp.int32).reshape((1,))
-        state = ops.region_fill(
-            state, fill_coords, fill_vals, nonempty, side=side, n=self.n,
-            scheme=self.scheme, tile=self.tile, policy=self.policy)
+        with jax.named_scope("ask.fill"):
+            # compact fill-OLT; pad with duplicates of the first live row
+            cap = coords.shape[0]
+            (idx,) = jnp.nonzero(homog, size=cap, fill_value=0)
+            count = jnp.sum(homog.astype(jnp.int32))
+            live = jnp.arange(cap) < count
+            idx = jnp.where(live, idx, idx[0])
+            fill_coords = coords[idx]
+            fill_vals = common[idx]
+            nonempty = (count > 0).astype(jnp.int32).reshape((1,))
+            state = ops.region_fill(
+                state, fill_coords, fill_vals, nonempty, side=side, n=self.n,
+                scheme=self.scheme, tile=self.tile, policy=self.policy)
 
         subdivide = jnp.logical_and(valid, jnp.logical_not(homog))
         return state, subdivide
@@ -147,16 +152,17 @@ class FrameProblem:
                   valid: jax.Array, *, level: int, bounds=None) -> jax.Array:
         bounds = self.bounds if bounds is None else bounds
         side = self.region_side(level)
-        # duplicate-pad the invalid tail (idempotent recompute)
-        cap = coords.shape[0]
-        count = jnp.sum(valid.astype(jnp.int32))
-        idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
-        coords = coords[idx]
-        nonempty = (count > 0).astype(jnp.int32).reshape((1,))
-        return ops.region_dwell(
-            state, coords, nonempty, side=side, n=self.n, bounds=bounds,
-            max_dwell=self.max_dwell, scheme=self.scheme, tile=self.tile,
-            policy=self.policy, workload=self.workload)
+        with jax.named_scope("ask.dwell"):
+            # duplicate-pad the invalid tail (idempotent recompute)
+            cap = coords.shape[0]
+            count = jnp.sum(valid.astype(jnp.int32))
+            idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
+            coords = coords[idx]
+            nonempty = (count > 0).astype(jnp.int32).reshape((1,))
+            return ops.region_dwell(
+                state, coords, nonempty, side=side, n=self.n, bounds=bounds,
+                max_dwell=self.max_dwell, scheme=self.scheme, tile=self.tile,
+                policy=self.policy, workload=self.workload)
 
     def preview_step(self, state: jax.Array, coords: jax.Array,
                      valid: jax.Array, *, level: int,
@@ -172,18 +178,22 @@ class FrameProblem:
         """
         bounds = self.bounds if bounds is None else bounds
         side = self.region_side(level)
-        _, common = ops.perimeter_query(
-            coords, side=side, n=self.n, bounds=bounds,
-            max_dwell=self.max_dwell, policy=self.policy,
-            workload=self.workload)
-        # live rows are the ring's contiguous prefix; duplicate-pad the tail
-        cap = coords.shape[0]
-        count = jnp.sum(valid.astype(jnp.int32))
-        idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
-        nonempty = (count > 0).astype(jnp.int32).reshape((1,))
-        return ops.region_fill(
-            state, coords[idx], common[idx], nonempty, side=side, n=self.n,
-            scheme=self.scheme, tile=self.tile, policy=self.policy)
+        with jax.named_scope("ask.query"):
+            _, common = ops.perimeter_query(
+                coords, side=side, n=self.n, bounds=bounds,
+                max_dwell=self.max_dwell, policy=self.policy,
+                workload=self.workload)
+        with jax.named_scope("ask.fill"):
+            # live rows are the ring's contiguous prefix; duplicate-pad
+            # the tail
+            cap = coords.shape[0]
+            count = jnp.sum(valid.astype(jnp.int32))
+            idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
+            nonempty = (count > 0).astype(jnp.int32).reshape((1,))
+            return ops.region_fill(
+                state, coords[idx], common[idx], nonempty, side=side,
+                n=self.n, scheme=self.scheme, tile=self.tile,
+                policy=self.policy)
 
     # -- dynamic-parameter protocol (batched frame serving) -----------------
     # ``extra`` is a traced [4] bounds array: one plane window per frame
@@ -214,23 +224,25 @@ class FrameProblem:
                           valid: jax.Array, *, level: int,
                           bounds_all) -> Tuple[jax.Array, jax.Array]:
         side = self.region_side(level)
-        homog, common = ops.perimeter_query(
-            rows[:, 1:], side=side, n=self.n,
-            bounds=ops.pooled_bounds(bounds_all, rows),
-            max_dwell=self.max_dwell, policy=self.policy,
-            workload=self.workload)
-        homog = jnp.logical_and(homog, valid)
+        with jax.named_scope("ask.query"):
+            homog, common = ops.perimeter_query(
+                rows[:, 1:], side=side, n=self.n,
+                bounds=ops.pooled_bounds(bounds_all, rows),
+                max_dwell=self.max_dwell, policy=self.policy,
+                workload=self.workload)
+            homog = jnp.logical_and(homog, valid)
 
-        # compact fill-OLT; pad with duplicates of the first live row
-        cap = rows.shape[0]
-        (idx,) = jnp.nonzero(homog, size=cap, fill_value=0)
-        count = jnp.sum(homog.astype(jnp.int32))
-        live = jnp.arange(cap) < count
-        idx = jnp.where(live, idx, idx[0])
-        nonempty = (count > 0).astype(jnp.int32).reshape((1,))
-        state = ops.region_fill_pooled(
-            state, rows[idx], common[idx], nonempty, side=side, n=self.n,
-            policy=self.policy)
+        with jax.named_scope("ask.fill"):
+            # compact fill-OLT; pad with duplicates of the first live row
+            cap = rows.shape[0]
+            (idx,) = jnp.nonzero(homog, size=cap, fill_value=0)
+            count = jnp.sum(homog.astype(jnp.int32))
+            live = jnp.arange(cap) < count
+            idx = jnp.where(live, idx, idx[0])
+            nonempty = (count > 0).astype(jnp.int32).reshape((1,))
+            state = ops.region_fill_pooled(
+                state, rows[idx], common[idx], nonempty, side=side,
+                n=self.n, policy=self.policy)
 
         subdivide = jnp.logical_and(valid, jnp.logical_not(homog))
         return state, subdivide
@@ -239,14 +251,15 @@ class FrameProblem:
                          valid: jax.Array, *, level: int,
                          bounds_all) -> jax.Array:
         side = self.region_side(level)
-        cap = rows.shape[0]
-        count = jnp.sum(valid.astype(jnp.int32))
-        idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
-        nonempty = (count > 0).astype(jnp.int32).reshape((1,))
-        return ops.region_dwell_pooled(
-            state, rows[idx], nonempty, side=side, n=self.n,
-            bounds_all=bounds_all, max_dwell=self.max_dwell,
-            policy=self.policy, workload=self.workload)
+        with jax.named_scope("ask.dwell"):
+            cap = rows.shape[0]
+            count = jnp.sum(valid.astype(jnp.int32))
+            idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
+            nonempty = (count > 0).astype(jnp.int32).reshape((1,))
+            return ops.region_dwell_pooled(
+                state, rows[idx], nonempty, side=side, n=self.n,
+                bounds_all=bounds_all, max_dwell=self.max_dwell,
+                policy=self.policy, workload=self.workload)
 
 
 # back-compat: the paper's case study is the default-workload FrameProblem

@@ -129,8 +129,8 @@ class DispatchRecord:
 
 class _FakeEngineHandle:
     """The engine-dispatch handle ``RenderService`` finalises:
-    ``finalize()`` blocks on the device timeline, then returns
-    ``(canvases, stats)``."""
+    ``wait()`` blocks on the device timeline; ``finalize()`` waits too,
+    then returns ``(canvases, stats)``."""
 
     def __init__(self, engine, record, canvases, stats):
         self._engine = engine
@@ -138,8 +138,11 @@ class _FakeEngineHandle:
         self._canvases = canvases
         self._stats = stats
 
-    def finalize(self):
+    def wait(self):
         self._engine.device.wait_until(self._record.ready_at)
+
+    def finalize(self):
+        self.wait()
         self._record.finalized_at = self._engine.clock.now()
         return self._canvases, self._stats
 
@@ -153,26 +156,31 @@ class FakeEngine:
     while every dispatch costs exactly ``compute_s(frames)`` of virtual
     device time. ``records`` holds one :class:`DispatchRecord` per
     dispatch, in enqueue order -- the material for exact-schedule
-    overlap assertions.
+    overlap assertions. ``enqueue_s`` is the host time each dispatch
+    call takes; ``overflow`` maps a dispatch's index to the rows each of
+    its frames dropped (``ASKStats.frame_overflow``), which sends those
+    frames through the service's retry loop.
     """
 
     def __init__(self, *, n: int, compute_s=1.0, clock=None,
-                 dtype=np.int32):
+                 dtype=np.int32, enqueue_s: float = 0.0, overflow=None):
         self.clock = clock if clock is not None else VirtualClock()
         self.device = FakeDevice(self.clock)
         self.n = int(n)
         self.dtype = dtype
         self._compute_s = (compute_s if callable(compute_s)
                           else (lambda f: float(compute_s)))
+        self._enqueue_s = float(enqueue_s)
+        self._overflow = dict(overflow or {})
         self.records = []
 
     @classmethod
-    def attach(cls, service, *, compute_s=1.0):
+    def attach(cls, service, *, compute_s=1.0, **kw):
         """Wire a fresh engine into ``service``: the service's clock is
         replaced by the engine's virtual clock and its ``_dispatch`` by
         the scripted one. Returns the engine."""
         eng = cls(n=service.n, compute_s=compute_s,
-                  dtype=service._dtype)
+                  dtype=service._dtype, **kw)
         service._clock = eng.clock
         service._dispatch = eng
         return eng
@@ -190,7 +198,11 @@ class FakeEngine:
         # encode frame identity so demux/order tests can see who is who
         for j, b in enumerate(rec.bounds):
             canvases[j, 0, 0] = np.asarray(b[0]).astype(self.dtype)
-        handle = _FakeEngineHandle(self, rec, canvases, _fake_stats(f))
+        stats = _fake_stats(f)
+        if rec.index in self._overflow:
+            stats.frame_overflow = tuple(self._overflow[rec.index])
+        self.clock.advance(self._enqueue_s)
+        handle = _FakeEngineHandle(self, rec, canvases, stats)
         return handle, self.clock.now() - t0
 
 

@@ -381,3 +381,79 @@ def test_feedback_observation_uses_own_chunks_workload_when_pipelined():
     assert observed == expected == ["mandelbrot", "julia"] * 3
     # and the measurements landed in their own namespaces
     assert {"mandelbrot", "julia"} <= set(est.workloads_observed())
+
+
+# ---------------------------------------------------------------------------
+# chunk phases (``ChunkStats.*_s``, the ``repro.*`` profiler spans)
+# ---------------------------------------------------------------------------
+
+def test_feedback_chunk_phases_split_fetch_on_the_virtual_clock():
+    """On the deterministic harness: ``fetch_s`` is exactly wait + stats
+    + copy + retry, ``dispatch_s`` is still the engine's own enqueue
+    time, the wait is the device time the chunk had left, and only the
+    chunk whose frame overflowed spends time in the retry loop."""
+    from fakes import FakeEngine
+
+    svc = _fb_svc(_prob(dwell=61), pipeline_depth=2, safety_factor=0.4,
+                  engine="ask_pooled")
+    # dispatch 1 is chunk 1's first: its second frame drops rows
+    eng = FakeEngine.attach(svc, compute_s=1.0, enqueue_s=0.25,
+                            overflow={1: (0, 3, 0, 0)})
+    chunks = [r.chunk for r in svc.stream_chunks(zoom_bounds(12))]
+    assert [c.index for c in chunks] == [0, 1, 2]
+    for c in chunks:
+        assert c.fetch_s == pytest.approx(
+            c.wait_s + c.stats_s + c.copy_s + c.retry_s)
+        assert c.dispatch_s == pytest.approx(0.25)
+        assert (c.retry_s > 0) == (c.retries > 0) == (c.index == 1)
+    # chunk 0 runs on the device over [0, 1] and is finalised from 0.5,
+    # after chunk 1's enqueue; chunk 1 runs over [1, 2] and is finalised
+    # from 1.25, after chunk 2's enqueue; chunk 2's device work ([2, 3])
+    # ended while chunk 1 retried
+    assert [c.wait_s for c in chunks] == pytest.approx([0.5, 0.75, 0.0])
+    # the retry queues behind chunk 2 on the serial device: it waits for
+    # chunk 2's second and its own
+    assert chunks[1].retry_s == pytest.approx(2.0)
+    assert [r.frames for r in eng.records] == [4, 4, 4, 1]
+
+
+def test_uniform_chunk_phases_split_fetch_on_the_virtual_clock():
+    """The uniform path reaches the same phases, less those it has no
+    work for: its canvases stay on the device and nothing retries."""
+    from fakes import FakeEngine
+
+    svc = _svc(_prob(), pipeline_depth=1)
+    FakeEngine.attach(svc, compute_s=1.0, enqueue_s=0.25)
+    chunks = [r.chunk for r in svc.stream_chunks(zoom_bounds(8))]
+    assert len(chunks) == 2
+    for c in chunks:
+        assert c.dispatch_s == pytest.approx(0.25)
+        # synchronous: the device time left after the enqueue returned
+        assert c.wait_s == pytest.approx(0.75)
+        assert c.fetch_s == pytest.approx(c.wait_s + c.stats_s)
+        assert c.copy_s == c.retry_s == c.observe_s == 0.0
+
+
+def test_chunk_phases_are_profiler_spans(tmp_path):
+    """Each phase of a real (CPU) chunk is a ``repro.<phase>`` host span
+    tagged with its chunk's index."""
+    import jax
+    from jax.profiler import ProfileData
+
+    svc = _fb_svc(_prob(dwell=62), pipeline_depth=2)
+    next(svc.stream_chunks(zoom_bounds(4)))  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    chunks = [r.chunk for r in svc.stream_chunks(zoom_bounds(8))]
+    jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    spans.setdefault(e.name, set()).add(
+                        dict(e.stats).get("chunk"))
+    assert {"repro.plan", "repro.dispatch", "repro.wait", "repro.stats",
+            "repro.copy", "repro.observe"} <= set(spans)
+    assert {c.index for c in chunks} <= spans["repro.wait"]
+    assert all(c.wait_s >= 0 and c.copy_s >= 0 for c in chunks)
