@@ -27,6 +27,10 @@ import jax.numpy as jnp
 # top-right (0.5, 1).
 DEFAULT_BOUNDS: Tuple[float, float, float, float] = (-1.5, -1.0, 0.5, 1.0)
 
+# The minor dimension of a TPU vector register, and of an array's (8, 128)
+# tiling in HBM.
+LANES = 128
+
 
 def map_coords(xs: jax.Array, ys: jax.Array, n: int,
                bounds: Tuple[float, float, float, float] = DEFAULT_BOUNDS):
@@ -196,16 +200,40 @@ def region_interior_dyn(coords: jax.Array, *, side: int, n: int,
                         bounds=DEFAULT_BOUNDS, max_dwell: int = 512,
                         workload=None, unroll: int = 1) -> jax.Array:
     """Un-jitted last-level work A (traced-bounds variant, see
-    ``perimeter_query_dyn``)."""
+    ``perimeter_query_dyn``): [N, side, side] values.
+
+    A TPU tiles an array's two minor dimensions as (8, 128), so planes
+    of ``[N, side, side]`` at a side below 128 fill side/128 of the
+    lanes, and the escape loop streams that padding through HBM on every
+    trip. Where side is not a multiple of 128 and side * side is, each
+    tile's pixels are laid out row-major as ``[side * side / 128, 128]``
+    instead, and the values come back as ``[N, side, side]`` after the
+    loop. The planes are built in that layout from the pixel index, not
+    reshaped into it: the compiler recomputes them inside the loop from
+    the per-row origins, and a reshape there would relayout the padded
+    planes on every trip. Pixel coordinates are exact small integers, so
+    every pixel sees the same f32 ops on the same values in either
+    layout, bit for bit. The Pallas bodies call ``dwell_compute`` on a
+    ``(side, side)`` block and never see this."""
+    area = side * side
+    if side % LANES and area % LANES == 0:
+        plane = (area // LANES, LANES)
+        pix = jnp.arange(area, dtype=jnp.int32).reshape(plane)
+        iy = (pix // side).astype(jnp.float32)
+        ix = (pix % side).astype(jnp.float32)
+    else:
+        plane = (side, side)
+        iy = jnp.arange(side, dtype=jnp.float32)[:, None]
+        ix = iy.T
     py = (coords[:, 0] * side).astype(jnp.float32)
     px = (coords[:, 1] * side).astype(jnp.float32)
-    iy = jnp.arange(side, dtype=jnp.float32)
-    ys = py[:, None, None] + iy[None, :, None]
-    xs = px[:, None, None] + iy[None, None, :]
-    ys = jnp.broadcast_to(ys, (coords.shape[0], side, side))
-    xs = jnp.broadcast_to(xs, (coords.shape[0], side, side))
+    planes = (coords.shape[0],) + plane
+    ys = jnp.broadcast_to(py[:, None, None] + iy, planes)
+    xs = jnp.broadcast_to(px[:, None, None] + ix, planes)
     cr, ci = map_coords(xs, ys, n, bounds)
-    return dwell_compute(cr, ci, max_dwell, workload=workload, unroll=unroll)
+    values = dwell_compute(cr, ci, max_dwell, workload=workload,
+                           unroll=unroll)
+    return values.reshape(coords.shape[0], side, side)
 
 
 @functools.partial(jax.jit,

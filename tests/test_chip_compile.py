@@ -82,17 +82,44 @@ def _check(compiled, label, canvas=None):
     return total
 
 
-def test_pooled_chunk_program_compiles(problem, frames_mesh):
+@pytest.fixture(scope="module")
+def pooled_compiled(problem, frames_mesh):
     from repro.core.pooled import _jitted_pooled, pooled_capacities
 
     caps = pooled_capacities(problem, (0.7,) * FRAMES)
     fn = _jitted_pooled(problem, caps, FRAMES, mesh=frames_mesh)
-    compiled = fn.lower(
+    return fn.lower(
         jax.ShapeDtypeStruct((1, FRAMES, 4), jnp.float32),
         jax.ShapeDtypeStruct((1, FRAMES), jnp.bool_)).compile()
+
+
+def test_pooled_chunk_program_compiles(pooled_compiled):
     # the output alone is 8 canvases of 64 MiB
-    assert (_check(compiled, "pooled", f"s32[{FRAMES * N},{N}]")
+    assert (_check(pooled_compiled, "pooled", f"s32[{FRAMES * N},{N}]")
             >= FRAMES * N * N * 4)
+
+
+def test_pooled_escape_loop_carries_lane_dense_planes(pooled_compiled,
+                                                      problem):
+    """Leaf dwell A's escape loop (the ``while`` under ``ask.dwell``)
+    streams its carry through HBM on every trip. Planes of
+    ``[rows, 32, 32]`` tile as (8, 128) and pad each to four times its
+    bytes; the loop must carry them 128 lanes wide."""
+    loops = [line for line in pooled_compiled.as_text().splitlines()
+             if re.search(r"= \(.*\) while\(.*op_name=\"[^\"]*ask\.dwell/"
+                          r"while\"", line)]
+    assert len(loops) == 1, loops
+    carry = loops[0].split(" while(")[0]
+    arrays = [(dtype, [int(d) for d in dims.split(",")])
+              for dtype, dims in re.findall(r"\b(\w+)\[([\d,]+)\]", carry)]
+    assert not [a for a in arrays if a[1][-1] == problem.B], carry
+    planes = [a for a in arrays if len(a[1]) >= 2]
+    assert all(dims[-1] == 128 for _, dims in planes), carry
+    pixels = max(int(np.prod(dims)) for _, dims in planes)
+    assert pixels % (problem.B * problem.B) == 0
+    # z's real and imaginary parts and the dwell, each over every leaf pixel
+    held = [dtype for dtype, dims in planes if int(np.prod(dims)) == pixels]
+    assert held.count("f32") >= 2 and held.count("s32") >= 1, carry
 
 
 def test_ask_scan_batch_program_compiles(problem, frames_mesh):

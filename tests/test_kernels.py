@@ -1,6 +1,8 @@
 """Per-kernel allclose validation: Pallas (interpret=True) vs ref.py
 oracle, swept over shapes/blocks/dwells per the deliverable-(c) contract."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -128,3 +130,79 @@ def test_moe_batched_ranks_kernel(n, e):
     want_r, want_c = batched_compact_ranks(flags)
     np.testing.assert_array_equal(np.asarray(ranks), np.asarray(want_r))
     np.testing.assert_array_equal(np.asarray(counts[0]), np.asarray(want_c))
+
+
+def _interior_on_tiles(coords, bounds, *, side, n, max_dwell, workload):
+    """Leaf dwell A written out on ``[N, side, side]`` planes: map_coords
+    then dwell_compute, with no other layout in between."""
+    py = (coords[:, 0] * side).astype(jnp.float32)
+    px = (coords[:, 1] * side).astype(jnp.float32)
+    iy = jnp.arange(side, dtype=jnp.float32)
+    tiles = (coords.shape[0], side, side)
+    ys = jnp.broadcast_to(py[:, None, None] + iy[None, :, None], tiles)
+    xs = jnp.broadcast_to(px[:, None, None] + iy[None, None, :], tiles)
+    cr, ci = ref.map_coords(xs, ys, n, bounds)
+    return ref.dwell_compute(cr, ci, max_dwell, workload=workload)
+
+
+# (side, rows): an 8 x 8 tile fills half a lane row, so side 8 keeps its
+# tiles whether the rows' pixels together fill whole lane rows (8, 6) or
+# not (8, 5); side 128 is lane-dense already
+_INTERIOR_SHAPES = [(8, 5), (8, 6), (16, 3), (32, 3), (64, 2), (128, 2)]
+
+
+@pytest.mark.parametrize("bounds_kind", ["static", "traced", "pooled"])
+@pytest.mark.parametrize("workload_name",
+                         ["mandelbrot", "julia", "burning_ship", "ssd_synth"])
+@pytest.mark.parametrize("side,rows", _INTERIOR_SHAPES)
+def test_region_interior_layout_is_bit_identical(side, rows, workload_name,
+                                                 bounds_kind):
+    """``region_interior_dyn`` runs A's loop on lane-dense planes where the
+    shape allows; every pixel's value must be the one the
+    ``[N, side, side]`` computation gives, bit for bit, for every
+    workload and every way the serving paths pass the window."""
+    from repro.workloads import get_workload
+
+    workload = get_workload(workload_name)
+    # four regions a side, so most of them straddle a dwell band's edge
+    n, max_dwell, frames = 4 * side, 64, 3
+    rng = np.random.default_rng(side * 100 + rows)
+    coords = jnp.asarray(rng.integers(0, n // side, (rows, 2)), jnp.int32)
+    re0, im0, re1, im1 = workload.default_bounds
+    # the default window and two zooms towards its middle
+    windows = [(re0 + (re1 - re0) * s, im0 + (im1 - im0) * s,
+                re1 - (re1 - re0) * s, im1 - (im1 - im0) * s)
+               for s in (0.0, 0.2, 0.35)]
+    kw = dict(side=side, n=n, max_dwell=max_dwell, workload=workload)
+    if bounds_kind == "static":
+        got = ref.region_interior_ref(coords, bounds=windows[1], **kw)
+        want = jax.jit(functools.partial(
+            _interior_on_tiles, bounds=windows[1], **kw))(coords)
+    else:
+        if bounds_kind == "traced":
+            bounds = jnp.asarray(windows[1], jnp.float32)
+        else:
+            frame = jnp.asarray(rng.integers(0, frames, rows), jnp.int32)
+            bounds = ops.pooled_bounds(
+                jnp.asarray(windows, jnp.float32),
+                jnp.concatenate([frame[:, None], coords], axis=1))
+        got = jax.jit(functools.partial(ref.region_interior_dyn, **kw))(
+            coords, bounds=bounds)
+        want = jax.jit(functools.partial(_interior_on_tiles, **kw))(
+            coords, bounds)
+    assert got.shape == (rows, side, side)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if workload.kind == "escape":
+        # the loop runs 128 lanes wide where side is below 128 and each
+        # tile's pixels fill whole lane rows; other shapes keep the tiles
+        jaxpr = jax.make_jaxpr(functools.partial(
+            ref.region_interior_dyn,
+            bounds=windows[1] if bounds_kind == "static" else bounds,
+            **kw))(coords)
+        loop, = [e for e in jaxpr.eqns
+                 if e.primitive.name in ("scan", "while")]
+        lane_dense = side % 128 and side * side % 128 == 0
+        assert {v.aval.shape for v in loop.outvars if v.aval.ndim} == {
+            (rows, side * side // 128, 128) if lane_dense
+            else (rows, side, side)}
