@@ -147,6 +147,13 @@ class ChunkStats:
     overflow re-dispatch loop (0 unless a frame overflowed);
     ``observe_s``: folding the counts into the estimator. ``fetch_s``
     spans ``wait_s + stats_s + copy_s + retry_s``.
+
+    ``shard_leaf_counts`` and ``shard_frames`` split the chunk over the
+    mesh's devices as the dispatch assigned it: device d holds frames
+    ``[d * S, (d + 1) * S)`` of the padded batch (frame-major, ``S`` =
+    padded width over devices). Each is one entry a device, counting the
+    live leaf regions (``ASKStats.frame_leaf_counts``) and the live
+    frames there; padding frames count on neither.
     """
 
     index: int
@@ -176,6 +183,8 @@ class ChunkStats:
     copy_s: float = 0.0
     retry_s: float = 0.0
     observe_s: float = 0.0
+    shard_leaf_counts: tuple = ()
+    shard_frames: tuple = ()
 
     @property
     def busy_s(self) -> float:
@@ -530,6 +539,17 @@ class RenderService:
     def _phase(self, name: str, chunk: int) -> _Phase:
         return _Phase(self._clock, name, chunk)
 
+    def _shards(self, frame_leaf_counts, pad: int):
+        """``(shard_leaf_counts, shard_frames)`` of a dispatch of
+        ``len(frame_leaf_counts)`` live frames padded to ``pad``:
+        frame-major, ``pad // devices`` frames a device."""
+        n_dev = int(self.mesh.devices.size)
+        per = pad // n_dev
+        shards = [frame_leaf_counts[d * per:(d + 1) * per]
+                  for d in range(n_dev)]
+        return (tuple(int(sum(s)) for s in shards),
+                tuple(len(s) for s in shards))
+
     def _enqueue(self, index: int, key: str, bounds, plan_s: float, *,
                  depths=None, p=None, caps=None, src: str = "") -> _Enqueued:
         """Dispatch one planned chunk inside its ``repro.dispatch``
@@ -551,11 +571,14 @@ class RenderService:
         with self._phase("stats", i) as stats:
             canvases, st = item.handle.finalize()
         fetch_s = self._clock.now() - t0
+        leaves, frames = self._shards(st.frame_leaf_counts,
+                                      self.chunk_frames)
         return ChunkResult(canvases, st, ChunkStats(
             index=i, frames=len(item.bounds), dispatch_s=item.dispatch_s,
             fetch_s=fetch_s, in_flight=in_flight, workload=item.key,
             tenants=tuple(tenants), plan_s=item.plan_s,
-            wait_s=wait.seconds, stats_s=stats.seconds))
+            wait_s=wait.seconds, stats_s=stats.seconds,
+            shard_leaf_counts=leaves, shard_frames=frames))
 
     def _pad_width(self, f: int) -> int:
         """Padding width of a feedback-path dispatch: the next power-of-
@@ -870,13 +893,16 @@ class RenderService:
                     + retry_rows)
         else:
             ring = self._pad_width(len(bounds)) * 2 * max(caps) + retry_rows
+        leaves, frames = self._shards(merged.frame_leaf_counts,
+                                      self._pad_width(len(bounds)))
         return ChunkResult(canv, merged, ChunkStats(
             index=i, frames=len(bounds), dispatch_s=item.dispatch_s,
             fetch_s=fetch_s, in_flight=in_flight, p_subdiv=item.p,
             p_source=item.src, retries=retries,
             ring_rows=ring, workload=key, tenants=tuple(tenants),
             plan_s=item.plan_s, wait_s=wait.seconds, stats_s=stats.seconds,
-            copy_s=copy.seconds, retry_s=retry_s, observe_s=observe.seconds))
+            copy_s=copy.seconds, retry_s=retry_s, observe_s=observe.seconds,
+            shard_leaf_counts=leaves, shard_frames=frames))
 
     # -- multi-tenant front-door seam ---------------------------------------
 

@@ -368,3 +368,73 @@ def test_render_service_pooled_sharded():
         print("OK")
     """)
     assert "OK" in out
+
+
+# The four-chip zoom-video host (``zoom4k_x4``): pooled feedback serving
+# on a 4-device ``frames`` mesh, 4 frames a device, fed a video dealt
+# serpentine so that each chunk lists its frames in rising depth.
+_ZOOM_HOST = """
+    import numpy as np
+    from repro.launch.mesh import make_frames_mesh
+    from repro.launch.render_service import RenderService, zoom_bounds
+    from repro.workloads import FrameProblem, exhaustive
+
+    prob = FrameProblem(n=256, g=4, r=2, B=32, max_dwell=64)
+    DEVICES, FRAMES, CHUNK = {devices}, {frames}, 16
+
+    def serpentine(num, chunk):
+        # chunk c takes frame c of the video's first stretch, the
+        # stretch's last-but-c of the second, c of the third, ...
+        k = -(-num // chunk)
+        order = []
+        for c in range(k):
+            order += [j * k + (c if j % 2 == 0 else k - 1 - c)
+                      for j in range(chunk)]
+        return [i for i in order if i < num]
+
+    video = list(zoom_bounds(FRAMES, zoom_per_frame=1.2))
+    bounds = [video[i] for i in serpentine(FRAMES, CHUNK)]
+
+    def serve(devices):
+        svc = RenderService(prob, mesh=make_frames_mesh(devices),
+                            chunk_frames=CHUNK, engine="ask_pooled",
+                            feedback=True)
+        canv, rs = svc.render(bounds)
+        return svc, canv, rs
+
+    svc, canv, rs = serve(DEVICES)
+    assert svc.chunk_frames == CHUNK and rs.overflow_dropped == 0
+    # the counters against the frames' own leaf counts, chunk by chunk
+    for r in svc.stream_chunks(bounds):
+        c, leaves = r.chunk, r.stats.frame_leaf_counts
+        assert len(c.shard_leaf_counts) == len(c.shard_frames) == DEVICES
+        assert sum(c.shard_frames) == c.frames
+        assert sum(c.shard_leaf_counts) == sum(leaves) == r.stats.leaf_count
+        per = svc._pad_width(c.frames) // DEVICES
+        for d in range(DEVICES):
+            mine = range(d * per, min((d + 1) * per, c.frames))
+            assert c.shard_frames[d] == len(mine), (c.shard_frames, per)
+            assert c.shard_leaf_counts[d] == sum(leaves[j] for j in mine)
+    if DEVICES > 1:
+        _, one, _ = serve(1)
+        np.testing.assert_array_equal(canv, one)
+    for b, got in zip(bounds, canv):
+        ex, _ = exhaustive(prob.n, max_dwell=prob.max_dwell, bounds=b)
+        np.testing.assert_array_equal(got, np.asarray(ex))
+    print("OK")
+"""
+
+
+@pytest.mark.parametrize("devices,frames", [(4, 32), (4, 37), (1, 37)])
+def test_zoom_host_shards(devices, frames):
+    """The four-chip zoom host's shape at n = 256: pooled feedback
+    serving over a serpentine-dealt zoom, 16-frame chunks. Four devices
+    render what one renders, bit for bit, and what the exhaustive
+    render gives (no border-filled pixel differs at this size);
+    ``ChunkStats.shard_leaf_counts`` and ``shard_frames`` split each
+    chunk's ``frame_leaf_counts`` frame-major over the devices, padding
+    excluded (37 frames leave a 5-frame chunk padded to 8); on one
+    device each is a 1-tuple."""
+    out = _run(_ZOOM_HOST.format(devices=devices, frames=frames),
+               devices=devices)
+    assert "OK" in out
