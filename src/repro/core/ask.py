@@ -622,9 +622,12 @@ class ShardedDispatch:
     async render service (``launch.render_service``, ``pipeline_depth >=
     2``) exploits exactly this: it enqueues chunk k+1 and only then calls
     ``finalize()`` on chunk k, so the host-side transfer of k overlaps the
-    device compute of k+1. ``finalize`` blocks, applies the pad-masking,
-    and returns the same ``(states, ASKStats)`` the synchronous entry
-    point does.
+    device compute of k+1. That holds because ``finalize`` queues no
+    device work on a full-width batch, here or in the pooled engine's
+    ``core.pooled.PooledDispatch``: the canvases are the program's own
+    output, ready when k's program ends, not behind k+1. ``finalize``
+    blocks, applies the pad-masking, and returns the same ``(states,
+    ASKStats)`` the synchronous entry point does.
     """
 
     states: Any  # padded [F_pad, ...] device arrays

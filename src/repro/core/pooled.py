@@ -334,10 +334,12 @@ _POOLED_CACHE_MAX = 128
 def _jitted_pooled(problem, caps: Tuple[int, ...], frames: int, mesh=None):
     """Build (or fetch) the jitted pooled pipeline.
 
-    ``mesh`` wraps the pipeline in a vmap over the SHARD axis: inputs
-    become ``[n_dev, S, ...]`` with ``frames = S`` frames pooled per
-    shard, placed via ``NamedSharding`` so each device runs its own pool
-    with zero collectives (the lax.switch level index stays unbatched).
+    ``mesh`` maps the pipeline over the SHARD axis: inputs become
+    ``[n_dev, S, ...]`` with ``frames = S`` frames pooled per shard, so
+    each device runs its own pool with zero collectives (the lax.switch
+    level index stays unbatched). The canvases come out frame-major,
+    ``[n_dev * S, n, n]``, as each device's ``[S, n, n]`` block; the
+    per-frame counters keep their leading shard axis.
     """
     try:
         key = (problem, caps, frames, mesh)
@@ -353,8 +355,8 @@ def _jitted_pooled(problem, caps: Tuple[int, ...], frames: int, mesh=None):
         from jax.sharding import PartitionSpec
 
         def one_pool(bounds_all, live):  # this device's [1, S, ...] block
-            out = pipeline(bounds_all[0], live[0])
-            return jax.tree_util.tree_map(lambda x: x[None], out)
+            states, *counters = pipeline(bounds_all[0], live[0])
+            return (states, *(x[None] for x in counters))
 
         # shard_map, not a vmap for GSPMD to partition: each device runs
         # the one-pool program on its own block (GSPMD partitioning of
@@ -465,11 +467,19 @@ def run_ask_pooled(
 @dataclasses.dataclass
 class PooledDispatch:
     """An in-flight sharded pooled batch (see ``core.ask.ShardedDispatch``
-    for the async-dispatch contract). Shapes carry a leading shard axis:
-    states [n_dev, S, n, n], entering [n_dev, levels, S], leaf/dropped
-    [n_dev, S]; frames are assigned frame-major (device d owns frames
-    d*S .. (d+1)*S - 1), so flattening the shard axes restores input
-    order. ``caps`` is the PER-SHARD shared ring sizing."""
+    for the async-dispatch contract). Frames are assigned frame-major
+    (device d owns frames d*S .. (d+1)*S - 1): ``states`` is the
+    program's own [F_pad, n, n] canvas output, already in input order;
+    the counters carry a leading shard axis (entering [n_dev, levels,
+    S], leaf/dropped [n_dev, S]). ``caps`` is the PER-SHARD shared ring
+    sizing.
+
+    ``finalize()`` queues no device work: it returns ``states`` as they
+    are, padded tail included (rows ``frames`` on are dead frames), and
+    reads the small counters back. So the host's copy of a chunk's
+    canvases starts when the chunk's program ends, while the next one
+    runs; callers that want the true frames slice ``[:frames]`` where
+    the canvases land (host or device)."""
 
     states: Any
     entering: Any
@@ -485,13 +495,12 @@ class PooledDispatch:
         jax.block_until_ready(self.states)
 
     def finalize(self, *, block_until_ready: bool = True) -> Tuple[Any, ASKStats]:
-        states = self.states
+        """Block, then read the counters back: ``(states, stats)``, the
+        states as the program left them ([F_pad, n, n]), the stats of
+        the ``frames`` true frames."""
         if block_until_ready:
             self.wait()
         F = self.frames
-        states = states.reshape((-1,) + states.shape[2:])
-        if int(states.shape[0]) != F:
-            states = states[:F]
         entering = jax.device_get(self.entering)  # [n_dev, levels, S]
         entering = np.moveaxis(entering, 1, 2).reshape(
             -1, entering.shape[1])[:F]
@@ -499,7 +508,7 @@ class PooledDispatch:
         dropped = jax.device_get(self.frame_dropped).reshape(-1)[:F]
         stats = _pooled_stats(self.caps, entering, leaf_f, dropped,
                               time.perf_counter() - self.t0)
-        return states, stats
+        return self.states, stats
 
 
 def dispatch_ask_pooled_sharded(
@@ -588,9 +597,13 @@ def run_ask_pooled_sharded(
 ) -> Tuple[Any, ASKStats]:
     """Synchronous wrapper over ``dispatch_ask_pooled_sharded`` +
     ``PooledDispatch.finalize`` (one pool per device shard; total ring
-    across the mesh is ``n_dev * stats.ring_rows``)."""
+    across the mesh is ``n_dev * stats.ring_rows``), with the padded
+    tail trimmed: states [F, n, n]."""
     d = dispatch_ask_pooled_sharded(
         problem, extras, mesh=mesh, capacities=capacities,
         frame_ps=frame_ps, p_subdiv=p_subdiv, safety_factor=safety_factor,
         pad_to=pad_to)
-    return d.finalize(block_until_ready=block_until_ready)
+    states, stats = d.finalize(block_until_ready=block_until_ready)
+    if int(states.shape[0]) != d.frames:
+        states = states[:d.frames]
+    return states, stats

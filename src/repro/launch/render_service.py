@@ -15,7 +15,7 @@ dispatch_ask_scan_sharded``):
     one compiled program in the jitted-pipeline cache
     (``core.ask._PIPELINE_CACHE``): one XLA dispatch per chunk, zero
     retracing for the life of the service;
-  * padded frames are masked out of canvases and stats by the engine, so
+  * padded frames are masked out of canvases and stats, so
     the streamed output is bit-identical to rendering each frame alone;
   * with ``pipeline_depth >= 2`` (the default is 2: double buffering) the
     service exploits JAX *async dispatch*: up to ``pipeline_depth``
@@ -570,6 +570,9 @@ class RenderService:
             item.handle.wait()
         with self._phase("stats", i) as stats:
             canvases, st = item.handle.finalize()
+            f = len(item.bounds)
+            if int(canvases.shape[0]) != f:  # a padded tail, on the device
+                canvases = canvases[:f]
         fetch_s = self._clock.now() - t0
         leaves, frames = self._shards(st.frame_leaf_counts,
                                       self.chunk_frames)
@@ -867,7 +870,9 @@ class RenderService:
         with self._phase("stats", i) as stats:
             canvases, st = item.handle.finalize()
         with self._phase("copy", i) as copy:
-            canv = np.asarray(canvases)
+            # the program's own output: the copy needs only this chunk's
+            # program to have ended; a padded tail is cut off here
+            canv = np.asarray(canvases)[:len(bounds)]
         canv, merged, retries, retry_rows, retry_s = self._resolve_overflow(
             key, bounds, caps, canv, st, index=i)
         fetch_s = self._clock.now() - t0  # retry dispatches included

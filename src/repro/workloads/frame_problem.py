@@ -501,10 +501,11 @@ def dispatch_batch(problem: FrameProblem, bounds_batch, *, mesh=None,
 
     The non-blocking half of ``solve_batch(..., mesh=...)``: returns a
     ``core.ask.ShardedDispatch`` handle as soon as the XLA call is
-    enqueued; ``.finalize()`` yields the same (canvases, ASKStats). The
-    pipelined render service (``launch.render_service``) uses this to
-    overlap the host copy of chunk k with the device compute of chunk
-    k+1. ``options`` (an ``EngineOptions`` carrying the mesh) is the
+    enqueued; ``.finalize()`` yields the same (canvases, ASKStats) (the
+    pooled engine's ``core.pooled.PooledDispatch`` keeps the padded
+    tail on its canvases). The pipelined render service
+    (``launch.render_service``) uses this to overlap the host copy of
+    chunk k with the device compute of chunk k+1. ``options`` (an ``EngineOptions`` carrying the mesh) is the
     canonical configuration spelling, as in ``solve_batch``.
     """
     from repro.core.ask import dispatch_ask_scan_sharded

@@ -346,6 +346,51 @@ def test_sharded_pooled_ragged_padding_single_device():
     assert st.overflow_dropped == 0
 
 
+def test_sharded_pooled_finalize_queues_no_device_work():
+    """A full-width chunk's ``finalize()`` hands back the program's own
+    frame-major canvases, so nothing is queued behind the chunk still in
+    flight and the host copy can start as soon as this chunk ends."""
+    prob = _prob()
+    bounds = np.asarray(_mixed_bounds(2, 1), np.float32)
+    mesh = make_frames_mesh(1)
+    d = pooled.dispatch_ask_pooled_sharded(prob, bounds, mesh=mesh,
+                                           safety_factor=1e9)
+    nxt = pooled.dispatch_ask_pooled_sharded(prob, bounds[::-1], mesh=mesh,
+                                             safety_factor=1e9)
+    canv, st = d.finalize()
+    assert canv is d.states
+    assert canv.shape == (3, prob.n, prob.n)
+    ref, st_ref = run_ask_scan_batch(prob, jnp.asarray(bounds),
+                                     safety_factor=1e9)
+    np.testing.assert_array_equal(np.asarray(canv), np.asarray(ref))
+    assert st.region_counts == st_ref.region_counts
+    assert st.frame_leaf_counts == st_ref.frame_leaf_counts
+    canv2, _ = nxt.finalize()
+    np.testing.assert_array_equal(np.asarray(canv2),
+                                  np.asarray(ref)[::-1])
+
+
+def test_sharded_pooled_finalize_leaves_padded_tail_to_caller():
+    """A padded chunk's ``finalize()`` also returns the program's own
+    output: the true frames first, then zero canvases for the dead
+    padding frames, with stats for the true frames alone."""
+    prob = _prob()
+    bounds = np.asarray(_mixed_bounds(2, 1), np.float32)  # F=3, pad to 4
+    d = pooled.dispatch_ask_pooled_sharded(prob, bounds,
+                                           mesh=make_frames_mesh(1),
+                                           pad_to=4, safety_factor=1e9)
+    canv, st = d.finalize()
+    assert canv is d.states and d.frames == 3
+    host = np.asarray(canv)
+    assert host.shape == (4, prob.n, prob.n)
+    assert not host[3].any()
+    ref, st_ref = pooled.run_ask_pooled_batch(prob, bounds,
+                                              safety_factor=1e9)
+    np.testing.assert_array_equal(host[:3], np.asarray(ref))
+    assert st.frame_leaf_counts == st_ref.frame_leaf_counts
+    assert len(st.region_counts) == 3
+
+
 # ---------------------------------------------------------------------------
 # pooled render-service chunking
 # ---------------------------------------------------------------------------
@@ -369,6 +414,24 @@ def test_pooled_service_uniform_stream_identical():
     assert rs.chunks == 3 and rs.dispatches_per_chunk == 1.0
     assert rs.overflow_dropped == 0
     assert rs.program_traces in (None, 1), rs.program_traces
+
+
+def test_pooled_service_uniform_stream_keeps_canvases_on_device():
+    """The uniform stream makes no host copy: full-width and padded
+    chunks alike hand back device arrays of the chunk's true frames."""
+    import jax
+
+    from repro.launch.render_service import RenderService, zoom_bounds
+
+    prob = _prob(dwell=35)
+    svc = RenderService(prob, engine="ask_pooled", mesh=make_frames_mesh(1),
+                        chunk_frames=4, safety_factor=1e9)
+    chunks = list(svc.stream_chunks(zoom_bounds(10)))
+    assert [c.chunk.frames for c in chunks] == [4, 4, 2]
+    for c in chunks:
+        assert isinstance(c.canvases, jax.Array)
+        assert c.canvases.shape == (c.chunk.frames, prob.n, prob.n)
+        assert c.chunk.copy_s == 0.0
 
 
 def test_pooled_chunker_keeps_class_jumps_inside_chunks():

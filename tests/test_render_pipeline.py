@@ -231,6 +231,37 @@ def test_feedback_pipelined_matches_sync_and_bounds_queue():
         assert all(c.stats.overflow_dropped == 0 for c in chunks)
 
 
+def test_pooled_feedback_pipelined_matches_sync_and_pool():
+    """The pooled feedback stream at depth 2, where each chunk's copy
+    runs while the next chunk computes, gives the canvases of depth 1
+    and of one pooled batch over every frame, padded tail included
+    (11 frames in chunks of 4, 4 and 3). ``fetch_s`` still splits into
+    its phases: on the wall clock the phases are disjoint pieces of it,
+    and what they leave out is the service's own bookkeeping."""
+    from repro.core.pooled import run_ask_pooled_batch
+
+    prob = _prob(dwell=45)
+    bounds = list(_skim_bounds(11))
+    results = {}
+    for depth in (1, 2):
+        svc = _fb_svc(prob, engine="ask_pooled", pipeline_depth=depth)
+        chunks = list(svc.stream_chunks(bounds))
+        assert [c.chunk.frames for c in chunks] == [4, 4, 3]
+        assert max(c.chunk.in_flight for c in chunks) == depth
+        for c in chunks:
+            assert isinstance(c.canvases, np.ndarray)
+            phases = (c.chunk.wait_s + c.chunk.stats_s + c.chunk.copy_s
+                      + c.chunk.retry_s)
+            assert phases <= c.chunk.fetch_s
+            assert c.chunk.fetch_s == pytest.approx(phases, abs=0.05)
+            assert c.stats.overflow_dropped == 0
+        results[depth] = np.concatenate([c.canvases for c in chunks])
+    ref, _ = run_ask_pooled_batch(prob, np.asarray(bounds, np.float32),
+                                  safety_factor=1e9)
+    np.testing.assert_array_equal(results[2], results[1])
+    np.testing.assert_array_equal(results[2], np.asarray(ref))
+
+
 def test_feedback_splits_chunk_on_capacity_class_jump():
     """Boundary-aware chunking: a stream whose density jumps mid-chunk
     is cut at the jump -- the cold prefix keeps its small ring and the
