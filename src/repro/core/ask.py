@@ -638,6 +638,7 @@ class ShardedDispatch:
     multiple: int  # padding multiple the batch was rounded up to
     caps: Tuple[int, ...]
     t0: float  # perf_counter at enqueue (finalize stamps wall_s from it)
+    program: Any  # the jitted program it was enqueued on (host-side)
 
     def wait(self) -> None:
         """Block until the batch's device work has ended."""
@@ -714,7 +715,7 @@ def dispatch_ask_scan_sharded(
     return ShardedDispatch(states=states, entering=entering,
                            leaf_counts=leaf_counts, dropped=dropped,
                            frames=F, multiple=multiple, caps=tuple(caps),
-                           t0=t0)
+                           t0=t0, program=fn)
 
 
 def run_ask_scan_sharded(

@@ -71,6 +71,8 @@ __all__ = [
     "plan_pooled",
     "worst_case_capacities",
     "escalate_capacities",
+    "Converged",
+    "retry_until_fit",
     "solve_planned",
     "solve_pooled",
 ]
@@ -323,6 +325,107 @@ def escalate_capacities(caps, worst, frames) -> Tuple[int, ...]:
     return tuple(min(2 * c, w) for c, w in zip(caps, worst))
 
 
+@dataclasses.dataclass(frozen=True)
+class Converged:
+    """What ``retry_until_fit`` returns: each frame's result from the
+    dispatch it fit in, in input order."""
+
+    states: Any  # host (numpy) pytree, leading axis the frames
+    chains: tuple  # per frame (region_counts, leaf_count)
+    tags: tuple  # per frame, the tag of the group it fit in
+    caps: Tuple[int, ...]  # the last dispatch's capacities
+    dispatches: int
+    retries: int  # frame re-dispatches (a frame can be retried twice)
+    retried_frames: tuple  # indices that overflowed at least once
+    ring_rows: int  # rows allocated across ALL dispatches, retries incl.
+    stats: tuple  # ASKStats per dispatch, issue order
+
+
+def retry_until_fit(dispatch, groups, next_caps, *, frames: int, rings,
+                    max_dispatches: int = 64, done=None) -> Converged:
+    """THE zero-drop retry loop of every batched path: ``solve_planned``,
+    ``solve_pooled`` and the render service's feedback chunks.
+
+    ``groups`` are the initial ``(caps, frame indices, tag)`` dispatches
+    (``tag`` is the caller's, handed back per frame in ``tags``);
+    ``dispatch(idx, caps) -> (states, ASKStats)`` renders frames ``idx``
+    at ``caps`` (None: the dispatch sizes itself, and its ``olt_caps``
+    say how). ``done`` is the first group's ``(states, stats)`` when the
+    caller has dispatched it already. Pending groups run in ascending
+    ring width. The frames of a dispatch whose ``frame_overflow`` entry
+    is nonzero go to ``next_caps(caps, tag, failed, chains, ran) ->
+    (caps, tag)``: ``failed`` their indices, ``chains`` their measured
+    ``(region_counts, leaf_count)``, ``ran`` the frames the dispatch
+    held. Failed frames whose target matches a pending group's caps join
+    it, so they share one dispatch. A dispatch of ``f`` frames allocates
+    ``rings(f)`` rings of ``2 * max(caps)`` rows.
+
+    ``caps`` in the result is the last dispatch's: under a doubling
+    rule, the largest any frame ran at. A dispatch whose frames all fit
+    and that held every frame in order hands its states back uncopied.
+    """
+    work = [(caps, list(idx), tag) for caps, idx, tag in groups if len(idx)]
+    out = treedef = last = None
+    chains: list = [None] * frames
+    tags: list = [None] * frames
+    retried: set = set()
+    all_stats: list = []
+    retries = ring_rows = 0
+    while work:
+        work.sort(key=lambda item: max(item[0] or (0,)))
+        caps, idx, tag = work.pop(0)
+        if len(all_stats) >= max_dispatches:
+            raise RuntimeError(
+                f"retry loop exceeded max_dispatches={max_dispatches} "
+                f"without converging; frames still pending: {sorted(idx)}")
+        states, st = done if done is not None else dispatch(idx, caps)
+        done = None
+        last = tuple(caps) if caps is not None else tuple(st.olt_caps)
+        ring_rows += rings(len(idx)) * 2 * max(last)
+        all_stats.append(st)
+
+        flat, td = jax.tree_util.tree_flatten(
+            jax.tree_util.tree_map(np.asarray, states))
+        ok = [j for j in range(len(idx)) if st.frame_overflow[j] == 0]
+        if (out is None and len(ok) == frames and idx == list(range(frames))
+                and all(leaf.shape[0] == frames for leaf in flat)):
+            out, treedef = flat, td
+        elif ok:
+            if out is None:
+                treedef = td
+                out = [np.zeros((frames,) + leaf.shape[1:], leaf.dtype)
+                       for leaf in flat]
+            sel = np.asarray([idx[j] for j in ok])
+            for out_leaf, leaf in zip(out, flat):
+                out_leaf[sel] = leaf[np.asarray(ok)]
+        for j in ok:
+            chains[idx[j]] = (st.region_counts[j], st.frame_leaf_counts[j])
+            tags[idx[j]] = tag
+
+        bad = [j for j in range(len(idx)) if st.frame_overflow[j] != 0]
+        if bad:
+            failed = [idx[j] for j in bad]
+            retried.update(failed)
+            retries += len(failed)
+            tgt, tgt_tag = next_caps(
+                last, tag, failed,
+                [(st.region_counts[j], st.frame_leaf_counts[j]) for j in bad],
+                len(idx))
+            tgt = tuple(tgt)
+            for item in work:
+                if item[0] is not None and tuple(item[0]) == tgt:
+                    item[1].extend(failed)
+                    break
+            else:
+                work.append((tgt, failed, tgt_tag))
+    return Converged(
+        states=jax.tree_util.tree_unflatten(treedef, out),
+        chains=tuple(chains), tags=tuple(tags), caps=last,
+        dispatches=len(all_stats), retries=retries,
+        retried_frames=tuple(sorted(retried)), ring_rows=ring_rows,
+        stats=tuple(all_stats))
+
+
 def workload_name(problem) -> str:
     """Registry name of the problem's workload ("" when spec-less)."""
     return getattr(getattr(problem, "workload", None), "name", "")
@@ -428,10 +531,7 @@ def plan_capacities(problem, bounds_batch, *,
     for``). Problems whose extras are not plane bounds can call
     ``estimate_frames``/``plan_from_p`` with their own width or P notion.
     """
-    arr = np.asarray(bounds_batch, np.float64)
-    if arr.ndim != 2 or arr.shape[1] != 4:
-        raise ValueError(f"bounds_batch must be [F, 4], got {arr.shape}")
-    widths = (arr[:, 2] - arr[:, 0]).tolist()
+    widths, _ = _frame_widths(problem, bounds_batch, ref_width)
     ests = estimate_frames(problem, widths, ref_width=ref_width,
                            p_deep=p_deep, slope=slope, p_min=p_min)
     return plan_from_p(problem, [e.p_subdiv for e in ests],
@@ -667,6 +767,39 @@ def _padded_count(F: int, mesh) -> int:
     return -(-F // n_dev) * n_dev
 
 
+def _checked_plan(extras, plan, plan_kw, build) -> Tuple[int, CapacityPlan]:
+    """(frames, plan) of a batch: ``build(**plan_kw)`` when no plan was
+    given; a given plan must cover the batch and takes no kwargs."""
+    leaves = jax.tree_util.tree_leaves(extras)
+    if not leaves:
+        raise ValueError("extras must contain at least one array leaf")
+    F = int(np.asarray(leaves[0]).shape[0])
+    if plan is None:
+        plan = build(**plan_kw)
+    elif plan_kw:
+        raise ValueError(
+            f"plan was given, so estimation kwargs {sorted(plan_kw)} would "
+            "be silently ignored -- drop them or drop the prebuilt plan")
+    if plan.frames != F:
+        raise ValueError(f"plan covers {plan.frames} frames, batch has {F}")
+    return F, plan
+
+
+def _plan_report(plan, F: int, done: Converged, frame_p, t0) -> PlanReport:
+    leaf = tuple(int(c) for _, c in done.chains)
+    return PlanReport(
+        plan=plan, frames=F, dispatches=done.dispatches,
+        retries=done.retries, retried_frames=done.retried_frames,
+        overflow_dropped=0,  # the loop only exits once every frame fits
+        leaf_count=sum(leaf),
+        region_counts=tuple(rc for rc, _ in done.chains),
+        frame_leaf_counts=leaf, frame_p_subdiv=tuple(frame_p),
+        frame_p_source=(tuple(fp.source for fp in plan.frame_plans)
+                        if plan.frame_plans else ("prior",) * F),
+        ring_rows=done.ring_rows, wall_s=time.perf_counter() - t0,
+        bucket_stats=done.stats)
+
+
 def solve_planned(problem, extras, *, plan: Union[CapacityPlan, None] = None,
                   mesh=None, num_buckets: int = 4,
                   safety_factor: float = 1.25,
@@ -681,14 +814,15 @@ def solve_planned(problem, extras, *, plan: Union[CapacityPlan, None] = None,
     extras); pass ``observed=`` there to blend measured occupancy from a
     ``core.feedback.OccupancyEstimator`` into the plan.
 
-    Buckets run in ascending capacity order, one compiled program each.
-    Any frame whose ``ASKStats.frame_overflow`` entry is nonzero is
-    re-planned: promoted into the next bucket's capacities if one exists,
-    otherwise its capacities are doubled per level (clamped at the
+    Buckets run in ascending capacity order, one compiled program each,
+    through ``retry_until_fit``. Any frame whose ``ASKStats.
+    frame_overflow`` entry is nonzero is re-planned: promoted into the
+    next bucket's capacities if one exists, otherwise its capacities
+    are doubled per level (``escalate_capacities``, clamped at the
     exhaustive worst case, which cannot overflow) -- so the loop
     terminates with ``overflow_dropped == 0`` without any manual
-    ``safety_factor`` tuning. Frames with the same retry target share one
-    dispatch.
+    ``safety_factor`` tuning. Frames with the same retry target share
+    one dispatch.
 
     Returns ``(states, PlanReport)`` with ``states`` a host (numpy) pytree
     whose leading axis is the frame axis in input order.
@@ -699,102 +833,34 @@ def solve_planned(problem, extras, *, plan: Union[CapacityPlan, None] = None,
     the policy schedules kernels, and the two compose through the
     problem object without any extra plumbing here.
     """
-    leaves = jax.tree_util.tree_leaves(extras)
-    if not leaves:
-        raise ValueError("extras must contain at least one array leaf")
-    F = int(np.asarray(leaves[0]).shape[0])
-    if plan is None:
-        plan = plan_frames(problem, extras, num_buckets=num_buckets,
-                           safety_factor=safety_factor, **plan_kw)
-    elif plan_kw:
-        raise ValueError(
-            f"plan was given, so estimation kwargs {sorted(plan_kw)} would "
-            "be silently ignored -- drop them or drop the prebuilt plan")
-    if plan.frames != F:
-        raise ValueError(f"plan covers {plan.frames} frames, batch has {F}")
-
+    F, plan = _checked_plan(
+        extras, plan, plan_kw,
+        lambda **kw: plan_frames(problem, extras, num_buckets=num_buckets,
+                                 safety_factor=safety_factor, **kw))
     worst = worst_case_capacities(problem)
-    report = PlanReport(plan=plan, frames=F)
     t0 = time.perf_counter()
 
-    out_leaves = None
-    treedef = None
-    leaf_counts = [0] * F
-    region_counts: list = [()] * F
-    frame_p: list = [float("nan")] * F
-    retried: set = set()
-    bucket_stats = []
-
-    # worklist ascending by ring width; (capacities, frame indices,
-    # position in plan.buckets or None once escalated beyond the plan,
-    # the planning P that sized these capacities -- escalated-past-the-
-    # plan entries keep the last bucket's P, the doubled caps speak for
-    # themselves). Empty buckets dispatch nothing but remain valid
+    # a group's tag: (its position in plan.buckets, or None once
+    # escalated beyond the plan; the planning P that sized its caps --
+    # escalated groups keep the last bucket's P, the doubled caps speak
+    # for themselves). Empty buckets dispatch nothing but remain valid
     # promotion targets.
-    work = [(b.capacities, list(b.frames), pos, b.p_subdiv)
-            for pos, b in enumerate(plan.buckets) if b.frames]
+    def next_caps(caps, tag, failed, chains, ran):
+        pos, p = tag
+        if pos is not None and pos + 1 < len(plan.buckets):
+            up = plan.buckets[pos + 1]
+            return up.capacities, (pos + 1, up.p_subdiv)
+        return escalate_capacities(caps, worst, failed), (None, p)
 
-    while work:
-        work.sort(key=lambda item: max(item[0]))
-        caps, idx, pos, p_used = work.pop(0)
-        if report.dispatches >= max_dispatches:
-            raise RuntimeError(
-                f"planner exceeded max_dispatches={max_dispatches} without "
-                f"converging; frames still pending: {sorted(idx)}")
-        states, st = _run_bucket(problem, _take_frames(extras, idx), caps,
-                                 mesh)
-        report.dispatches += 1
-        report.ring_rows += _padded_count(len(idx), mesh) * 2 * max(caps)
-        bucket_stats.append(st)
-
-        host = jax.tree_util.tree_map(np.asarray, states)
-        flat, td = jax.tree_util.tree_flatten(host)
-        if out_leaves is None:
-            treedef = td
-            out_leaves = [np.zeros((F,) + leaf.shape[1:], leaf.dtype)
-                          for leaf in flat]
-        ok = [j for j in range(len(idx)) if st.frame_overflow[j] == 0]
-        if ok:
-            sel = np.asarray([idx[j] for j in ok])
-            for out_leaf, leaf in zip(out_leaves, flat):
-                out_leaf[sel] = leaf[np.asarray(ok)]
-            for j in ok:
-                leaf_counts[idx[j]] = st.frame_leaf_counts[j]
-                region_counts[idx[j]] = st.region_counts[j]
-                frame_p[idx[j]] = p_used
-
-        failed = [idx[j] for j in range(len(idx))
-                  if st.frame_overflow[j] != 0]
-        if failed:
-            retried.update(failed)
-            report.retries += len(failed)
-            if pos is not None and pos + 1 < len(plan.buckets):
-                tgt_caps = plan.buckets[pos + 1].capacities
-                tgt_pos: Union[int, None] = pos + 1
-                tgt_p = plan.buckets[pos + 1].p_subdiv
-            else:
-                tgt_caps = escalate_capacities(caps, worst, failed)
-                tgt_pos = None
-                tgt_p = p_used
-            for item in work:
-                if item[0] == tgt_caps:
-                    item[1].extend(failed)
-                    break
-            else:
-                work.append((tgt_caps, list(failed), tgt_pos, tgt_p))
-
-    report.wall_s = time.perf_counter() - t0
-    report.retried_frames = tuple(sorted(retried))
-    report.leaf_count = sum(int(c) for c in leaf_counts)
-    report.region_counts = tuple(region_counts)
-    report.frame_leaf_counts = tuple(int(c) for c in leaf_counts)
-    report.frame_p_subdiv = tuple(frame_p)
-    report.frame_p_source = (tuple(fp.source for fp in plan.frame_plans)
-                             if plan.frame_plans else ("prior",) * F)
-    report.overflow_dropped = 0  # the loop only exits once every frame fits
-    report.bucket_stats = tuple(bucket_stats)
-    states_out = jax.tree_util.tree_unflatten(treedef, out_leaves)
-    return states_out, report
+    done = retry_until_fit(
+        lambda idx, caps: _run_bucket(problem, _take_frames(extras, idx),
+                                      caps, mesh),
+        [(b.capacities, b.frames, (pos, b.p_subdiv))
+         for pos, b in enumerate(plan.buckets)],
+        next_caps, frames=F, rings=lambda f: _padded_count(f, mesh),
+        max_dispatches=max_dispatches)
+    report = _plan_report(plan, F, done, [p for _, p in done.tags], t0)
+    return done.states, report
 
 
 def solve_pooled(problem, extras, *, plan: Union[CapacityPlan, None] = None,
@@ -810,142 +876,58 @@ def solve_pooled(problem, extras, *, plan: Union[CapacityPlan, None] = None,
     ``extras`` must be the [F, 4] bounds array -- the pooled kernels
     evaluate each row in its own frame's window.
 
-    Overflow stays per frame: any frame with a nonzero
-    ``ASKStats.frame_overflow`` entry is re-pooled at capacities doubled
-    per level, clamped at the pooled worst case for the retry pool's own
-    size (``pooled.escalate_pooled_capacities`` -- which cannot
-    overflow), so the loop terminates with ``overflow_dropped == 0``.
-    Under a mesh the initial dispatch sizes each shard's ring from its
-    OWN members' P (``frame_ps``), and ``ring_rows`` counts
-    ``n_dev x 2 x max(caps)`` per dispatch -- the actual pooled
-    allocation, against which the per-frame plan's ``ring_rows``
-    benchmark comparison is made.
+    Overflow stays per frame: ``retry_until_fit`` re-pools any frame
+    with a nonzero ``ASKStats.frame_overflow`` entry at the capacities
+    of ``pooled.pooled_retry_capacities`` (first retry sized from the
+    overflowing frames alone, later ones doubled, clamped at the retry
+    pool's own worst case -- which cannot overflow), so the loop
+    terminates with ``overflow_dropped == 0``. Under a mesh the initial
+    dispatch sizes each shard's ring from its OWN members' P
+    (``frame_ps``), and ``ring_rows`` counts ``n_dev x 2 x max(caps)``
+    per dispatch -- the actual pooled allocation, against which the
+    per-frame plan's ``ring_rows`` benchmark comparison is made.
     """
     from repro.core import pooled as pooled_lib
 
-    leaves = jax.tree_util.tree_leaves(extras)
-    if not leaves:
-        raise ValueError("extras must contain at least one array leaf")
-    F = int(np.asarray(leaves[0]).shape[0])
-    if plan is None:
-        plan = plan_pooled(problem, extras, safety_factor=safety_factor,
-                           **plan_kw)
-    elif plan_kw:
-        raise ValueError(
-            f"plan was given, so estimation kwargs {sorted(plan_kw)} would "
-            "be silently ignored -- drop them or drop the prebuilt plan")
+    F, plan = _checked_plan(
+        extras, plan, plan_kw,
+        lambda **kw: plan_pooled(problem, extras,
+                                 safety_factor=safety_factor, **kw))
     if not plan.pooled:
         raise ValueError(
             "solve_pooled needs a pooled plan (plan_pooled / "
             "CapacityPlan(pooled=True)); per-frame plans run under "
             "solve_planned")
-    if plan.frames != F:
-        raise ValueError(f"plan covers {plan.frames} frames, batch has {F}")
-
-    worst = worst_case_capacities(problem)
     n_dev = 1 if mesh is None else int(mesh.devices.size)
     p_used = plan.buckets[0].p_subdiv
     ps_all = (tuple(e.p_subdiv for e in plan.estimates)
               or (p_used,) * F)  # hand-built plans may omit estimates
-    report = PlanReport(plan=plan, frames=F)
+    sf = plan.safety_factor
     t0 = time.perf_counter()
 
-    out_leaves = None
-    treedef = None
-    leaf_counts = [0] * F
-    region_counts: list = [()] * F
-    frame_p: list = [float("nan")] * F
-    retried: set = set()
-    bucket_stats = []
+    def per_shard(f):
+        return f if mesh is None else -(-f // n_dev)
 
-    # (capacities-or-None, frame indices): None sizes the initial pool
-    # from the plan (unsharded) / the members' own frame_ps (sharded)
-    work: list = [(None, list(range(F)))]
-    while work:
-        caps_exp, idx = work.pop(0)
-        if report.dispatches >= max_dispatches:
-            raise RuntimeError(
-                f"pooled planner exceeded max_dispatches={max_dispatches} "
-                f"without converging; frames still pending: {sorted(idx)}")
+    def dispatch(idx, caps):
         sel = _take_frames(extras, idx)
         if mesh is None:
-            caps = (caps_exp if caps_exp is not None
-                    else plan.buckets[0].capacities)
-            states, st = pooled_lib.run_ask_pooled_batch(
-                problem, sel, capacities=caps)
-        elif caps_exp is not None:
-            states, st = pooled_lib.run_ask_pooled_sharded(
-                problem, sel, mesh=mesh, capacities=caps_exp)
-        else:
-            states, st = pooled_lib.run_ask_pooled_sharded(
-                problem, sel, mesh=mesh,
-                frame_ps=[ps_all[i] for i in idx],
-                safety_factor=plan.safety_factor)
-        caps_used = st.olt_caps
-        report.dispatches += 1
-        report.ring_rows += n_dev * 2 * max(caps_used)
-        bucket_stats.append(st)
+            return pooled_lib.run_ask_pooled_batch(problem, sel,
+                                                   capacities=caps)
+        # caps None: the initial pool, each shard sized from its members
+        return pooled_lib.run_ask_pooled_sharded(
+            problem, sel, mesh=mesh, capacities=caps, safety_factor=sf,
+            frame_ps=None if caps is not None else [ps_all[i] for i in idx])
 
-        host = jax.tree_util.tree_map(np.asarray, states)
-        flat, td = jax.tree_util.tree_flatten(host)
-        if out_leaves is None:
-            treedef = td
-            out_leaves = [np.zeros((F,) + leaf.shape[1:], leaf.dtype)
-                          for leaf in flat]
-        ok = [j for j in range(len(idx)) if st.frame_overflow[j] == 0]
-        if ok:
-            sel_idx = np.asarray([idx[j] for j in ok])
-            for out_leaf, leaf in zip(out_leaves, flat):
-                out_leaf[sel_idx] = leaf[np.asarray(ok)]
-            for j in ok:
-                leaf_counts[idx[j]] = st.frame_leaf_counts[j]
-                region_counts[idx[j]] = st.region_counts[j]
-                frame_p[idx[j]] = p_used
+    def next_caps(caps, retry, failed, chains, ran):
+        return pooled_lib.pooled_retry_capacities(
+            problem, caps, chains, retry=retry,
+            ran_per_shard=per_shard(ran),
+            next_per_shard=per_shard(len(failed)),
+            frame_ps=[ps_all[i] for i in failed], safety_factor=sf), retry + 1
 
-        failed = [idx[j] for j in range(len(idx))
-                  if st.frame_overflow[j] != 0]
-        if failed:
-            retried.update(failed)
-            report.retries += len(failed)
-            shard_frames = (len(failed) if mesh is None
-                            else -(-len(failed) // n_dev))
-            ran_frames = (len(idx) if mesh is None
-                          else -(-len(idx) // n_dev))
-            if caps_exp is None:
-                # First failure of the initial pool: size the retry ring
-                # from ONLY the overflowing frames' measured contribution
-                # instead of doubling the whole-batch pool.
-                bad = [j for j in range(len(idx))
-                       if st.frame_overflow[j] != 0]
-                tgt = pooled_lib.failed_pool_capacities(
-                    problem,
-                    [tuple(st.region_counts[j]) for j in bad],
-                    leaf_counts=[int(st.frame_leaf_counts[j]) for j in bad],
-                    frames_per_shard=shard_frames,
-                    frame_ps=[ps_all[i] for i in failed],
-                    caps_prev=caps_used,
-                    dispatched_per_shard=ran_frames,
-                    safety_factor=plan.safety_factor)
-            else:
-                tgt = pooled_lib.escalate_pooled_capacities(
-                    caps_used, worst, shard_frames, failed,
-                    dispatched_per_shard=ran_frames)
-            for item in work:
-                if item[0] == tgt:
-                    item[1].extend(failed)
-                    break
-            else:
-                work.append((tgt, list(failed)))
-
-    report.wall_s = time.perf_counter() - t0
-    report.retried_frames = tuple(sorted(retried))
-    report.leaf_count = sum(int(c) for c in leaf_counts)
-    report.region_counts = tuple(region_counts)
-    report.frame_leaf_counts = tuple(int(c) for c in leaf_counts)
-    report.frame_p_subdiv = tuple(frame_p)
-    report.frame_p_source = (tuple(fp.source for fp in plan.frame_plans)
-                             if plan.frame_plans else ("prior",) * F)
-    report.overflow_dropped = 0  # the loop only exits once every frame fits
-    report.bucket_stats = tuple(bucket_stats)
-    states_out = jax.tree_util.tree_unflatten(treedef, out_leaves)
-    return states_out, report
+    first = plan.buckets[0].capacities if mesh is None else None
+    done = retry_until_fit(dispatch, [(first, range(F), 0)], next_caps,
+                           frames=F, rings=lambda f: n_dev,
+                           max_dispatches=max_dispatches)
+    report = _plan_report(plan, F, done, (p_used,) * F, t0)
+    return done.states, report

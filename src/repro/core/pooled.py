@@ -61,10 +61,11 @@ import numpy as np
 from repro.core import olt as olt_lib
 from repro.core.ask import ASKStats, _frames_axis, _per_frame_counts
 from repro.core.cost_model import expected_level_counts, num_levels
+from repro.core.planner import worst_case_capacities
 from repro.kernels import ops as ops_lib
 
-__all__ = ["PooledDispatch", "pooled_capacities",
-           "escalate_pooled_capacities", "failed_pool_capacities",
+__all__ = ["PooledDispatch", "pooled_capacities", "shard_capacities",
+           "pooled_retry_capacities",
            "run_ask_pooled", "run_ask_pooled_batch",
            "run_ask_pooled_sharded", "dispatch_ask_pooled_sharded"]
 
@@ -96,88 +97,68 @@ def pooled_capacities(problem, frame_ps: Sequence[float], *,
     return tuple(caps)
 
 
-def escalate_pooled_capacities(caps, worst, frames_per_shard: int,
-                               frames, *,
-                               dispatched_per_shard: int = None,
-                               ) -> Tuple[int, ...]:
-    """THE pooled overflow-escalation step: double each level's shared
-    capacity, clamped at the pooled worst case ``S * (g r^l)^2`` for the
-    ``S = frames_per_shard`` frames the retry ring will serve next.
+def shard_capacities(problem, frame_ps: Sequence[float], per_shard: int, *,
+                     safety_factor: float = 2.0) -> Tuple[int, ...]:
+    """Shared ring capacities for a frame-major sharded pool: per level
+    the max over shards (``per_shard`` frames each, a ragged last one
+    included) of the shard's ``pooled_capacities``. Every shard runs the
+    one compiled program, so the ring fits the fullest shard."""
+    caps = None
+    for d0 in range(0, len(frame_ps), per_shard):
+        c = pooled_capacities(problem, frame_ps[d0:d0 + per_shard],
+                              safety_factor=safety_factor)
+        caps = c if caps is None else tuple(map(max, caps, c))
+    return caps
 
-    The impossibility check and the clamp use DIFFERENT pool sizes when
-    the retry pool shrinks: a frame that overflowed while sharing a ring
-    with ``dispatched_per_shard`` frames (default: ``frames_per_shard``)
-    only proves the SHARED ring was short -- alone it may fit at, or
-    even below, the caps it just dropped rows at. So the defensive
-    RuntimeError (mirroring ``planner.escalate_capacities``: a pool at
-    its own worst case cannot overflow, reaching it with frames still
-    dropping is a bug) fires only when ``caps`` already covered the
-    worst case of the pool that ACTUALLY ran; the returned caps are
-    doubled but clamped at the NEXT pool's ceiling -- possibly below
-    ``caps``, which is fine because the pool shrank with them. ``frames``
-    only labels the error."""
-    ran = frames_per_shard if dispatched_per_shard is None \
-        else dispatched_per_shard
-    hi_ran = tuple(max(1, int(ran)) * w for w in worst)
+
+def pooled_retry_capacities(problem, caps, failed_chains, *, retry: int,
+                            ran_per_shard: int, next_per_shard: int,
+                            frame_ps=None,
+                            safety_factor: float = 2.0) -> Tuple[int, ...]:
+    """THE pooled overflow-retry rule: per-shard shared capacities to
+    re-pool the frames that overflowed a pool at ``caps``.
+
+    ``failed_chains`` are those frames' measured ``(region_counts,
+    leaf_count)`` in the dispatch that overflowed; a shard held
+    ``ran_per_shard`` frames there and holds ``next_per_shard`` in the
+    retry; ``retry`` counts the retries the frames already had.
+
+    First retry (``retry == 0``): re-pooling at the WHOLE previous
+    pool's doubled capacities would size a ring for frames that already
+    fit, so the ring comes from the failed frames alone -- per level,
+    twice their measured live rows (doubling covers the children the
+    drops truncated) or their own pooled estimate from ``frame_ps``,
+    whichever is larger. Later retries double each level. Both clamp at
+    the retry pool's worst case ``next_per_shard * (g r^l)^2``, possibly
+    below ``caps``: the pool shrank with them.
+
+    The impossibility check uses the pool that RAN: a frame that
+    overflowed while sharing a ring only proves the SHARED ring was
+    short, but a pool already at the worst case of its own
+    ``ran_per_shard`` frames cannot overflow, so reaching it with frames
+    still dropping is a bug, not a sizing problem (RuntimeError, as
+    ``planner.escalate_capacities``)."""
+    worst = worst_case_capacities(problem)
+    hi_ran = tuple(max(1, int(ran_per_shard)) * w for w in worst)
     if tuple(min(c, h) for c, h in zip(caps, hi_ran)) == hi_ran:
         raise RuntimeError(
-            f"frames {sorted(frames)} overflow at pooled worst-case "
-            "capacities")
-    hi = tuple(max(1, int(frames_per_shard)) * w for w in worst)
-    return tuple(min(2 * c, h) for c, h in zip(caps, hi))
-
-
-def failed_pool_capacities(problem, entered, *, frames_per_shard: int,
-                           leaf_counts=None, frame_ps=None, caps_prev=None,
-                           dispatched_per_shard: int = None,
-                           safety_factor: float = 2.0) -> Tuple[int, ...]:
-    """First-retry ring sizing from ONLY the overflowing frames.
-
-    When a shared pool undersizes for one capacity class, re-pooling the
-    failed frames at the WHOLE previous pool's doubled capacities (the
-    blunt ``escalate_pooled_capacities`` step) allocates a retry ring
-    sized for frames that already fit. The per-frame attribution the
-    pooled pipeline keeps -- ``entered``: each failed frame's measured
-    per-level live counts (region_counts), ``leaf_counts``: each failed
-    frame's leaf rows (the ``levels`` index of the ladder), and optionally
-    ``frame_ps``: the failed frames' own planning Ps -- sizes the retry
-    ring from their contribution alone: per level, double the larger of
-    the failed frames' measured live rows (doubling covers the children
-    the drops truncated) and their own pooled estimate, clamped at the
-    retry pool's worst case ``frames_per_shard * (g r^l)^2``.
-
-    ``caps_prev`` keeps the blunt step's impossibility check: a pool
-    that already covered the worst case of the ``dispatched_per_shard``
-    frames it ran cannot legitimately overflow (a drop there is a bug,
-    not capacity pressure). Repeated failures fall back to doubling via
-    ``escalate_pooled_capacities``, so the retry loop still terminates.
-    """
-    n, g, r, B = problem.n, problem.g, problem.r, problem.B
-    levels = num_levels(n, g, r, B)
-    S = max(1, int(frames_per_shard))
-    worst = tuple((g * r ** lv) ** 2 for lv in range(levels + 1))
-    if caps_prev is not None:
-        ran = (S if dispatched_per_shard is None
-               else max(1, int(dispatched_per_shard)))
-        hi_ran = tuple(ran * w for w in worst)
-        if tuple(min(c, h) for c, h in zip(caps_prev, hi_ran)) == hi_ran:
-            raise RuntimeError(
-                "frames overflow at pooled worst-case capacities")
-    est = (pooled_capacities(problem, frame_ps,
-                             safety_factor=safety_factor)
+            f"{len(failed_chains)} frames overflow at pooled worst-case "
+            f"capacities {tuple(caps)}")
+    hi = tuple(max(1, int(next_per_shard)) * w for w in worst)
+    if retry:
+        return tuple(min(2 * c, h) for c, h in zip(caps, hi))
+    est = (pooled_capacities(problem, frame_ps, safety_factor=safety_factor)
            if frame_ps else None)
-    caps = []
-    for lv in range(levels + 1):
+    levels = len(worst) - 1
+    out = []
+    for lv, h in enumerate(hi):
         if lv == levels:
-            meas = (sum(int(c) for c in leaf_counts)
-                    if leaf_counts is not None else 0)
+            meas = sum(int(leaf) for _, leaf in failed_chains)
         else:
-            meas = sum(int(c[lv]) for c in entered if lv < len(c))
-        need = 2 * meas
-        if est is not None:
-            need = max(need, est[lv])
-        caps.append(max(1, min(need, S * worst[lv])))
-    return tuple(caps)
+            meas = sum(int(rc[lv]) for rc, _ in failed_chains if lv < len(rc))
+        need = 2 * meas if est is None else max(2 * meas, est[lv])
+        out.append(max(1, min(need, h)))
+    return tuple(out)
 
 
 def _resolve_pooled_capacities(problem, frames: int, capacities, frame_ps,
@@ -489,6 +470,7 @@ class PooledDispatch:
     caps: Tuple[int, ...]
     n_dev: int
     t0: float
+    program: Any  # the jitted program it was enqueued on (host-side)
 
     def wait(self) -> None:
         """Block until the batch's device work has ended."""
@@ -563,13 +545,7 @@ def dispatch_ask_pooled_sharded(
         if len(ps) != F:
             raise ValueError(
                 f"frame_ps covers {len(ps)} frames, batch has {F}")
-        caps = None
-        for d in range(n_dev):
-            shard_ps = ps[d * S:min((d + 1) * S, F)]
-            c = pooled_capacities(problem, shard_ps,
-                                  safety_factor=safety_factor)
-            caps = c if caps is None else tuple(
-                max(a, b) for a, b in zip(caps, c))
+        caps = shard_capacities(problem, ps, S, safety_factor=safety_factor)
     else:
         caps = pooled_capacities(problem, (float(p_subdiv),) * S,
                                  safety_factor=safety_factor)
@@ -580,7 +556,7 @@ def dispatch_ask_pooled_sharded(
         bounds_all.reshape(n_dev, S, 4), live.reshape(n_dev, S))
     return PooledDispatch(states=states, entering=entering, leaf_f=leaf_f,
                           frame_dropped=frame_dropped, frames=F,
-                          caps=tuple(caps), n_dev=n_dev, t0=t0)
+                          caps=tuple(caps), n_dev=n_dev, t0=t0, program=fn)
 
 
 def run_ask_pooled_sharded(

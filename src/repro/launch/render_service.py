@@ -5,8 +5,8 @@ I/O of chunk k overlapped against the device compute of chunk k+1.
 A zoom trajectory can be millions of frames -- far more than one batch
 should hold -- so the service chunks the stream into fixed-size,
 device-divisible batches and pushes each chunk through the sharded scan
-pipeline (``mandelbrot.dispatch_batch`` / ``core.ask.
-dispatch_ask_scan_sharded``):
+pipeline (``core.ask.dispatch_ask_scan_sharded``, or ``core.pooled.
+dispatch_ask_pooled_sharded`` for ``engine="ask_pooled"``):
 
   * chunk size is a multiple of the mesh device count, so every device
     owns ``chunk/devices`` frames and the GSPMD partition is collective-free;
@@ -40,10 +40,10 @@ dispatch_ask_scan_sharded``):
     power-of-two bucketed (``_pad_width``), so the compiled-program
     cache stays keyed on (chunk width, capacity signature) with both
     factors bounded for the life of the service.
-    Frames that still overflow are retried at doubled capacities (clamped
-    at the worst case) before the chunk is yielded: ``overflow_dropped ==
-    0`` holds chunk by chunk, and the measured counts that come back --
-    retries included -- are what the estimator folds in.
+    Frames that still overflow are retried (``planner.retry_until_fit``)
+    before the chunk is yielded: ``overflow_dropped == 0`` holds chunk
+    by chunk, and the measured counts that come back -- retries
+    included -- are what the estimator folds in.
 
 ``python -m repro.launch.render_service --frames 64 --n 256`` runs a
 self-timed trajectory end to end and prints both pipelined and
@@ -64,9 +64,16 @@ from pathlib import Path
 from typing import Any, Iterable, Iterator, Mapping, NamedTuple, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
+from repro.core.ask import ASKStats, dispatch_ask_scan_sharded, scan_capacities
 from repro.core.feedback import OccupancyEstimator
+from repro.core.olt import next_pow2
+from repro.core.planner import (escalate_capacities, retry_until_fit,
+                                worst_case_capacities, zoom_depth)
+from repro.core.pooled import (dispatch_ask_pooled_sharded,
+                               pooled_retry_capacities, shard_capacities)
 from repro.launch.mesh import make_frames_mesh
 
 # frames each device renders per dispatch when the caller doesn't pin a
@@ -375,11 +382,13 @@ class RenderService:
     occupancies. On the feedback path the chunker then cuts only on
     workload switches (heterogeneous frames are the point of pooling --
     a capacity-class jump stays inside the chunk, see
-    ``_pooled_chunks``), the retry loop escalates the shared pool
-    (``pooled.escalate_pooled_capacities``), and ``ChunkStats.
-    ring_rows`` counts ``n_dev x 2 x max(caps)`` per dispatch -- the
-    pooled allocation the feedback benchmark compares against the
-    per-frame path's ``pad x 2 x max(caps)``.
+    ``_feedback_chunks``), the retry loop re-pools the overflowing frames
+    (``pooled.pooled_retry_capacities``), and ``ChunkStats.ring_rows``
+    counts ``n_dev x 2 x max(caps)`` per dispatch -- the pooled
+    allocation the feedback benchmark compares against the per-frame
+    path's ``pad x 2 x max(caps)``. The constructor resolves the engine
+    once, into the dispatch function, the chunker, the chunk's
+    capacities, the retry rule and the rings a dispatch allocates.
     """
 
     def __init__(self, problem, *, mesh=None, chunk_frames: int | None = None,
@@ -497,12 +506,26 @@ class RenderService:
         self._clock = _WALL if clock is None else clock
         self._caps_cache: dict = {}  # (problem key, quantized P) -> capacities
         self._used_sigs: set = set()  # (problem key, pad width, caps) dispatched
+        self._programs: dict = {}  # id -> each jitted program dispatched
         self._planned_index = 0  # ChunkStats.index of dispatch_planned batches
+        if engine == "ask_pooled":
+            self._engine_dispatch = dispatch_ask_pooled_sharded
+            self._chunk_class = lambda key, p: key  # cut on workload only
+            self._chunk_caps = self._pooled_caps_for
+            self._retry_rule = self._pooled_retry
+            self._rings = lambda f: n_dev  # one shared ring a device
+        else:
+            self._engine_dispatch = dispatch_ask_scan_sharded
+            self._chunk_class = lambda key, p: (key, p)
+            self._chunk_caps = lambda key, ps: self._caps_for(key, max(ps))
+            self._retry_rule = self._frame_retry
+            self._rings = self._pad_width  # one ring a frame, padding incl.
 
     # -- dispatch plumbing --------------------------------------------------
 
     def _dispatch(self, chunk, caps=None, key: str = ""):
-        """Enqueue one chunk; returns (ShardedDispatch, enqueue seconds).
+        """Enqueue one chunk; returns (the engine's dispatch handle,
+        enqueue seconds).
 
         ``caps`` (feedback path) overrides the engine kwargs' sizing with
         a per-chunk capacity vector and pads to the pow2-bucketed width
@@ -512,9 +535,6 @@ class RenderService:
         width, capacity signature) and nothing retraces across chunks
         that share a signature.
         """
-        from repro.workloads import dispatch_batch
-        from repro.workloads.options import EngineOptions
-
         kw = dict(self.engine_kw)
         pad = self.chunk_frames
         if caps is not None:
@@ -522,18 +542,10 @@ class RenderService:
             pad = self._pad_width(len(chunk))
             self._used_sigs.add((key, pad, tuple(caps)))
         t0 = self._clock.now()
-        if self.engine == "ask_pooled":
-            # the pooled engine is selected through EngineOptions (the
-            # legacy flat-kwargs path predates engines); capacities are
-            # then PER-SHARD shared pool caps, which is exactly what
-            # _pooled_caps_for / the pooled escalation produce
-            opts = EngineOptions.from_kwargs(
-                {**kw, "mesh": self.mesh, "pad_to": pad},
-                engine="ask_pooled")
-            d = dispatch_batch(self._problems[key], chunk, options=opts)
-        else:
-            d = dispatch_batch(self._problems[key], chunk, mesh=self.mesh,
-                               pad_to=pad, **kw)
+        d = self._engine_dispatch(self._problems[key],
+                                  jnp.asarray(chunk, jnp.float32),
+                                  mesh=self.mesh, pad_to=pad, **kw)
+        self._programs[id(d.program)] = d.program
         return d, self._clock.now() - t0
 
     def _phase(self, name: str, chunk: int) -> _Phase:
@@ -619,8 +631,6 @@ class RenderService:
         return key, bounds
 
     def _depth(self, key: str, bounds) -> float:
-        from repro.core.planner import zoom_depth
-
         return zoom_depth(float(bounds[2]) - float(bounds[0]),
                           ref_width=self._ref_widths[key],
                           r=self._problems[key].r)
@@ -633,8 +643,6 @@ class RenderService:
         ck = (key, round(float(p), 6))
         caps = self._caps_cache.get(ck)
         if caps is None:
-            from repro.core.ask import scan_capacities
-
             prob = self._problems[key]
             caps = scan_capacities(
                 prob.n, prob.g, prob.r, prob.B, p_subdiv=ck[1],
@@ -642,218 +650,138 @@ class RenderService:
             self._caps_cache[ck] = caps
         return caps
 
-    def _adaptive_chunks(self, it: Iterator):
-        """Boundary-aware chunker: yields (key, bounds, depths, p, caps,
-        source) with every frame of a chunk in ONE problem and ONE
-        predicted capacity class. A class jump -- or a workload switch
-        in a mixed stream -- cuts the chunk early: deep-tail frames get
-        their own (hotter) program instead of inflating the whole
-        chunk's ring, and every dispatch stays single-workload. Lazy:
-        predictions are made as frames are pulled, so re-planning
-        naturally picks up whatever the estimator has observed by then.
+    def _feedback_chunks(self, it: Iterator):
+        """Boundary-aware chunker: yields (key, bounds, ``_enqueue``
+        kwargs: depths, p, caps, src) with every frame of a chunk in ONE
+        problem and ONE chunk class (``_chunk_class``); a class jump cuts
+        the chunk early. The per-frame engine's class is the problem and
+        the predicted P, so deep-tail frames get their own (hotter)
+        program instead of inflating the whole chunk's ring. The pooled engine's class is
+        the problem alone: heterogeneous frames are the POINT of pooling
+        -- one shared ring sized from their summed occupancies -- so a
+        P jump stays inside the chunk. Either way every dispatch stays
+        single-workload; ``caps`` is ``_chunk_caps`` of the members' P,
+        and ``p`` the hottest member's. Lazy: predictions are made as
+        frames are pulled, so re-planning naturally picks up whatever
+        the estimator has observed by then.
         """
         est = self.estimator
         buf: list = []
         depths: list = []
+        ps: list = []
         sources: list = []
-        cls = None  # (problem key, quantized P, capacities) of the open chunk
+        cls = key_open = None  # class and problem key of the open chunk
 
         def flush():
             src = (sources[0] if len(set(sources)) == 1 else "mixed")
-            return cls[0], list(buf), list(depths), cls[1], cls[2], src
+            return key_open, list(buf), dict(
+                depths=list(depths), p=max(ps),
+                caps=self._chunk_caps(key_open, ps), src=src)
 
         for item in it:
             key, b = self._split_item(item)
             wl = self._problems[key].workload
             d = self._depth(key, b)
             p = est.predict_quantized(d, workload=wl)
-            caps = self._caps_for(key, p)
-            if buf and (key, p, caps) != cls:
+            if buf and self._chunk_class(key, p) != cls:
                 yield flush()
-                buf, depths, sources = [], [], []
+                buf, depths, ps, sources = [], [], [], []
                 # the estimator may have observed the flushed chunk while
                 # this generator was suspended in that yield: re-predict
                 # the held-over frame so the new chunk's class and
                 # provenance both reflect the post-observation state
                 p = est.predict_quantized(d, workload=wl)
-                caps = self._caps_for(key, p)
-            cls = (key, p, caps)
+            cls, key_open = self._chunk_class(key, p), key
             buf.append(b)
             depths.append(d)
+            ps.append(p)
             sources.append("measured"
                            if est.measured(d, workload=wl) is not None
                            else "prior")
             if len(buf) == self.chunk_frames:
                 yield flush()
-                buf, depths, sources, cls = [], [], [], None
+                buf, depths, ps, sources = [], [], [], []
+                cls = None
         if buf:
             yield flush()
 
     def _pooled_caps_for(self, key: str, ps):
         """Shared per-shard ring capacities for one pooled chunk: the
         members' expected occupancies are summed per shard (frame-major
-        assignment, live frames only; ``core.pooled.pooled_capacities``),
-        maxed across shards so every shard runs the one compiled
-        program, then rounded up to powers of two (clamped at the shard
-        worst case) -- so the capacity-signature set stays bounded even
-        though every chunk carries its own P mix."""
-        from repro.core.olt import next_pow2
-        from repro.core.planner import worst_case_capacities
-        from repro.core.pooled import pooled_capacities
-
+        assignment, live frames only), maxed across shards so every
+        shard runs the one compiled program (``core.pooled.
+        shard_capacities``), then rounded up to powers of two (clamped
+        at the shard worst case) -- so the capacity-signature set stays
+        bounded even though every chunk carries its own P mix."""
         prob = self._problems[key]
-        n_dev = int(self.mesh.devices.size)
-        S = self._pad_width(len(ps)) // n_dev
+        S = self._pad_width(len(ps)) // int(self.mesh.devices.size)
         sf = self.engine_kw.get("safety_factor", 2.0)
-        caps = None
-        for d in range(n_dev):
-            shard = ps[d * S:(d + 1) * S]
-            if not shard:
-                continue
-            c = pooled_capacities(prob, shard, safety_factor=sf)
-            caps = c if caps is None else tuple(
-                max(a, b) for a, b in zip(caps, c))
+        caps = shard_capacities(prob, ps, S, safety_factor=sf)
         worst = worst_case_capacities(prob)
         return tuple(min(next_pow2(c), S * w) for c, w in zip(caps, worst))
 
-    def _pooled_chunks(self, it: Iterator):
-        """Pooled chunker: yields the same (key, bounds, depths, p, caps,
-        source) tuples as ``_adaptive_chunks``, but a chunk is cut ONLY
-        on a workload switch or when full. Heterogeneous frames are the
-        POINT of pooling -- one shared ring sized from their summed
-        occupancies -- so a predicted capacity-class jump stays inside
-        the chunk instead of splitting it into per-class dispatches.
-        ``caps`` is the per-shard pooled vector (``_pooled_caps_for``);
-        ``p`` reports the hottest member's prediction."""
-        est = self.estimator
-        buf: list = []
-        depths: list = []
-        ps: list = []
-        sources: list = []
-        key_open: str | None = None
+    def _frame_retry(self, key, bounds, caps, tag, failed, chains, ran):
+        """Per-frame engine's retry rule: double, clamped at the worst
+        case (``planner.escalate_capacities``)."""
+        worst = worst_case_capacities(self._problems[key])
+        return escalate_capacities(caps, worst, failed), tag
 
-        def flush():
-            src = (sources[0] if len(set(sources)) == 1 else "mixed")
-            return (key_open, list(buf), list(depths), max(ps),
-                    self._pooled_caps_for(key_open, ps), src)
-
-        for item in it:
-            key, b = self._split_item(item)
-            if buf and key != key_open:
-                yield flush()
-                buf, depths, ps, sources = [], [], [], []
-            key_open = key
-            wl = self._problems[key].workload
-            d = self._depth(key, b)
-            # predicted AFTER any flush above resumes, so the pool's
-            # sizing reflects whatever the estimator observed by then
-            ps.append(est.predict_quantized(d, workload=wl))
-            sources.append("measured"
-                           if est.measured(d, workload=wl) is not None
-                           else "prior")
-            buf.append(b)
-            depths.append(d)
-            if len(buf) == self.chunk_frames:
-                yield flush()
-                buf, depths, ps, sources = [], [], [], []
-                key_open = None
-        if buf:
-            yield flush()
+    def _pooled_retry(self, key, bounds, caps, retry, failed, chains, ran):
+        """Pooled engine's retry rule (``pooled.pooled_retry_capacities``):
+        pools of pow2-bucketed width, and the failed frames' own
+        estimated P for the first retry's sizing."""
+        prob = self._problems[key]
+        n_dev = int(self.mesh.devices.size)
+        ps = [float(self.estimator.predict_quantized(
+                  self._depth(key, bounds[j]), workload=prob.workload))
+              for j in failed]
+        return pooled_retry_capacities(
+            prob, caps, chains, retry=retry,
+            ran_per_shard=self._pad_width(ran) // n_dev,
+            next_per_shard=self._pad_width(len(failed)) // n_dev,
+            frame_ps=ps), retry + 1
 
     def _resolve_overflow(self, key, bounds, caps, canv, st, *, index: int):
-        """Retry overflowing frames at doubled capacities until every
-        frame fits, then merge canvases/stats. ``canv`` holds the chunk's
-        canvases on the host. Returns (canvases np, merged ASKStats,
-        frame re-dispatch count, retry ring rows, retry seconds); the
-        retry loop runs in the chunk's ``repro.retry`` phase, opened only
-        when a frame overflowed.
+        """Retry overflowing frames until every frame fits
+        (``planner.retry_until_fit`` with the engine's retry rule), then
+        merge canvases/stats. ``canv`` holds the chunk's canvases on the
+        host. Returns (canvases np, merged ASKStats, frame re-dispatch
+        count, ring rows of every dispatch, retry seconds); the retry
+        loop runs in the chunk's ``repro.retry`` phase, opened only when
+        a frame overflowed.
 
-        The merged stats' ``olt_caps`` are the LARGEST capacities any of
-        the chunk's frames ran at (the escalated vector when retries
-        happened), so ``ASKStats.ring_rows`` never under-reports the
-        per-frame residency of a hot chunk; the per-dispatch total incl.
-        padding lives in ``ChunkStats.ring_rows``."""
-        from repro.core.ask import ASKStats
-        from repro.core.planner import (escalate_capacities,
-                                        worst_case_capacities)
+        The merged stats' ``olt_caps`` are the last dispatch's
+        capacities (the escalated vector when retries happened), so
+        ``ASKStats.ring_rows`` follows the ring the chunk's frames
+        finished in; the per-dispatch total incl. padding lives in
+        ``ChunkStats.ring_rows``."""
+        def dispatch(idx, c):
+            d, _ = self._dispatch([bounds[j] for j in idx], caps=c, key=key)
+            return d.finalize()
+
+        def rule(*args):
+            return self._retry_rule(key, bounds, *args)
 
         f = len(bounds)
-        chains = list(st.frame_chains())
-        launches = st.kernel_launches
-        wall = st.wall_s
-        retries = 0
-        retry_rows = 0
-        cur = tuple(caps)
-        pending = [j for j, o in enumerate(st.frame_overflow) if o]
-        n_dev = int(self.mesh.devices.size)
-        retry = self._phase("retry", index) if pending else None
+        retry = self._phase("retry", index) if any(st.frame_overflow) else None
         with retry or contextlib.nullcontext():
-            if pending:
-                canv = np.array(canv)  # writable copy for the row merges
-                worst = worst_case_capacities(self._problems[key])
-            ran = self._pad_width(f) // n_dev  # pool width, last dispatch
-            first = True
-            while pending:
-                if self.engine == "ask_pooled":
-                    from repro.core.pooled import (escalate_pooled_capacities,
-                                                   failed_pool_capacities)
-
-                    nxt = self._pad_width(len(pending)) // n_dev
-                    if first and self.estimator is not None:
-                        # First retry: size the ring from ONLY the pending
-                        # frames' measured chains + their own estimated P,
-                        # not a doubling of the whole chunk's shared pool.
-                        prob = self._problems[key]
-                        ps = [float(self.estimator.predict_quantized(
-                                  self._depth(key, bounds[j]),
-                                  workload=prob.workload))
-                              for j in pending]
-                        cur = failed_pool_capacities(
-                            prob, [chains[j][0] for j in pending],
-                            leaf_counts=[chains[j][1] for j in pending],
-                            frames_per_shard=nxt, frame_ps=ps,
-                            caps_prev=cur, dispatched_per_shard=ran)
-                    else:
-                        cur = escalate_pooled_capacities(
-                            cur, worst, nxt, pending, dispatched_per_shard=ran)
-                    ran = nxt
-                else:
-                    cur = escalate_capacities(cur, worst, pending)
-                first = False
-                d, _ = self._dispatch([bounds[j] for j in pending], caps=cur,
-                                      key=key)
-                rc, rst = d.finalize()
-                if self.engine == "ask_pooled":
-                    # shared pool: one ring of 2*max(cur) rows PER DEVICE
-                    retry_rows += n_dev * 2 * max(cur)
-                else:
-                    retry_rows += self._pad_width(len(pending)) * 2 * max(cur)
-                retries += len(pending)
-                launches += rst.kernel_launches
-                wall += rst.wall_s
-                rc = np.asarray(rc)
-                still = []
-                for k, j in enumerate(pending):
-                    if rst.frame_overflow[k] == 0:
-                        canv[j] = rc[k]
-                        chains[j] = (rst.region_counts[k],
-                                     rst.frame_leaf_counts[k])
-                    else:
-                        still.append(j)
-                pending = still
+            done = retry_until_fit(dispatch, [(tuple(caps), range(f), 0)],
+                                   rule, frames=f, rings=self._rings,
+                                   done=(canv, st))
+        chains = done.chains
         merged = ASKStats(
             levels=max((len(c) for c, _ in chains), default=0),
-            kernel_launches=launches,
+            kernel_launches=sum(s.kernel_launches for s in done.stats),
             region_counts=tuple(c for c, _ in chains),
             leaf_count=sum(leaf for _, leaf in chains),
             overflow_dropped=0,  # the loop only exits once every frame fits
-            wall_s=wall,
-            olt_caps=cur,  # == caps when nothing retried
+            wall_s=sum(s.wall_s for s in done.stats),
+            olt_caps=done.caps,  # == caps when nothing retried
             frame_overflow=(0,) * f,
             frame_leaf_counts=tuple(leaf for _, leaf in chains),
         )
         retry_s = retry.seconds if retry else 0.0
-        return canv, merged, retries, retry_rows, retry_s
+        return done.states, merged, done.retries, done.ring_rows, retry_s
 
     def _finalize_feedback(self, item, in_flight: int, tenants=(),
                            tenant_feedback: bool = False) -> ChunkResult:
@@ -873,7 +801,7 @@ class RenderService:
             # the program's own output: the copy needs only this chunk's
             # program to have ended; a padded tail is cut off here
             canv = np.asarray(canvases)[:len(bounds)]
-        canv, merged, retries, retry_rows, retry_s = self._resolve_overflow(
+        canv, merged, retries, ring, retry_s = self._resolve_overflow(
             key, bounds, caps, canv, st, index=i)
         fetch_s = self._clock.now() - t0  # retry dispatches included
         prob = self._problems[key]
@@ -892,12 +820,6 @@ class RenderService:
                             [chains[j] for j in idxs],
                             g=prob.g, r=prob.r, workload=prob.workload,
                             tenant=t)
-        if self.engine == "ask_pooled":
-            # ONE shared ring per device shard, not one per frame
-            ring = (int(self.mesh.devices.size) * 2 * max(caps)
-                    + retry_rows)
-        else:
-            ring = self._pad_width(len(bounds)) * 2 * max(caps) + retry_rows
         leaves, frames = self._shards(merged.frame_leaf_counts,
                                       self._pad_width(len(bounds)))
         return ChunkResult(canv, merged, ChunkStats(
@@ -998,53 +920,52 @@ class RenderService:
                        else "prior"
                        for j, d in enumerate(depths)}
             src = sources.pop() if len(sources) == 1 else "mixed"
-            if self.engine == "ask_pooled":
-                caps = self._pooled_caps_for(key, ps)
-            else:
-                caps = self._caps_for(key, max(ps))
+            caps = self._chunk_caps(key, ps)
         item = self._enqueue(index, key, bounds, plan.seconds, depths=depths,
                              p=max(ps), caps=caps, src=src)
         return PlannedDispatch(self, item, tenants, tenant_feedback)
 
-    def _stream_feedback(self, bounds_iter: Iterable) -> Iterator[ChunkResult]:
-        """The closed loop: re-plan, dispatch, retry, observe, refill."""
-        chunker = (self._pooled_chunks if self.engine == "ask_pooled"
-                   else self._adaptive_chunks)
-        chunks = chunker(iter(bounds_iter))
+    def _pipelined(self, pull, finalize) -> Iterator[ChunkResult]:
+        """Keep up to ``pipeline_depth`` chunks in flight. ``pull()``
+        plans the next chunk inside its ``repro.plan`` span -> ``(key,
+        bounds, _enqueue kwargs)``, or None once the stream ends;
+        ``finalize(item, in_flight)`` blocks on the oldest chunk (younger
+        ones compute behind it). The queue is refilled AFTER each
+        finalize -- which folds the feedback path's counts into the
+        estimator -- and BEFORE the yield, so the next chunk is planned
+        from the freshest finalised state while the devices stay busy
+        behind the consumer. ``pipeline_depth == 1`` is synchronous: at
+        most one chunk in flight, the next planned AND dispatched only
+        after the consumer returns."""
         pending: collections.deque = collections.deque()
         index = 0
 
         def enqueue() -> bool:
             nonlocal index
             with self._phase("plan", index) as plan:
-                item = next(chunks, None)
+                item = pull()
             if item is None:
                 return False
-            key, bounds, depths, p, caps, src = item
+            key, bounds, kw = item
             pending.append(self._enqueue(index, key, bounds, plan.seconds,
-                                         depths=depths, p=p, caps=caps,
-                                         src=src))
+                                         **kw))
             index += 1
             return True
 
-        if self.pipeline_depth == 1:  # synchronous: at most one in flight,
-            # and the next chunk is planned AND dispatched only after the
-            # consumer returns (the uniform path's depth-1 contract) --
-            # which also means it always plans from the freshest state
+        if self.pipeline_depth == 1:
             while enqueue():
-                yield self._finalize_feedback(pending.popleft(), in_flight=1)
+                yield finalize(pending.popleft(), in_flight=1)
             return
-
         while len(pending) < self.pipeline_depth and enqueue():
             pass
         while pending:
             in_flight = len(pending)
+            # ``item`` keeps the finalised chunk's device buffers alive
+            # until the next finalize: on a four-chip v5e host, freeing
+            # them before the next enqueue lowered peak memory by one
+            # chunk's canvases but added ~50 ms to that enqueue
             item = pending.popleft()
-            result = self._finalize_feedback(item, in_flight)
-            # refill AFTER observing (inside _finalize_feedback) and
-            # BEFORE yielding: the next chunk is planned from the
-            # freshest finalised state while the devices stay busy
-            # behind the consumer
+            result = finalize(item, in_flight)
             enqueue()
             yield result
 
@@ -1059,42 +980,24 @@ class RenderService:
         chunk k+1 while the consumer of the stream is still busy with
         chunk k. Chunk order (and therefore frame order) is preserved.
 
-        With ``feedback=`` set the stream re-plans each chunk's
+        With ``feedback=`` set -- the closed loop: re-plan, dispatch,
+        retry, observe, refill -- the stream re-plans each chunk's
         capacities from the estimator state before dispatch (see
-        ``_stream_feedback``); chunks may then be SHORTER than
-        ``chunk_frames`` where the predicted capacity class jumps.
+        ``_feedback_chunks``); chunks may then be SHORTER than
+        ``chunk_frames`` where the chunk class jumps.
         """
         if self.estimator is not None:
-            yield from self._stream_feedback(bounds_iter)
+            chunks = self._feedback_chunks(iter(bounds_iter))
+            yield from self._pipelined(lambda: next(chunks, None),
+                                       self._finalize_feedback)
             return
         it = iter(bounds_iter)
-        pending: collections.deque = collections.deque()
-        index = 0
 
-        def enqueue() -> bool:
-            nonlocal index
-            with self._phase("plan", index) as plan:
-                chunk = list(itertools.islice(it, self.chunk_frames))
-            if not chunk:
-                return False
-            pending.append(self._enqueue(index, "", chunk, plan.seconds))
-            index += 1
-            return True
+        def pull():
+            chunk = list(itertools.islice(it, self.chunk_frames))
+            return ("", chunk, {}) if chunk else None
 
-        if self.pipeline_depth == 1:  # synchronous: at most one in flight
-            while enqueue():
-                yield self._finalize_uniform(pending.popleft(), in_flight=1)
-            return
-
-        while len(pending) < self.pipeline_depth and enqueue():
-            pass
-        while pending:
-            in_flight = len(pending)
-            # younger chunks compute behind this one's finalize
-            result = self._finalize_uniform(pending.popleft(), in_flight)
-            enqueue()  # refill BEFORE yielding: devices stay busy while the
-            #            consumer processes this chunk
-            yield result
+        yield from self._pipelined(pull, self._finalize_uniform)
 
     def stream(self, bounds_iter: Iterable):
         """Yield (canvases [f, n, n], ASKStats) per chunk (the PR-2
@@ -1103,64 +1006,24 @@ class RenderService:
             yield r.canvases, r.stats
 
     def program_traces(self) -> int | None:
-        """Traced signatures of this service's chunk program(s) so far.
+        """Traced signatures of the chunk program(s) this service has
+        dispatched so far (None when jax doesn't expose the jit cache).
 
-        Measured off the jitted pipeline in ``core.ask``'s cache (the
-        exact object every chunk dispatches through), so it is a real
-        regression signal: pinning ``pad_to`` to the chunk width must keep
-        this at 1 no matter how ragged the trajectory tail is. On the
-        feedback path the count is summed across the capacity signatures
-        the stream dispatched; its regression target is
-        ``RenderStats.plan_signatures`` -- each signature compiled once,
-        every chunk sharing a signature reusing that program.
+        Summed over the jitted programs the dispatch handles carried
+        (the exact objects every chunk dispatches through), so it is a
+        real regression signal: pinning ``pad_to`` to the chunk width
+        must keep this at 1 no matter how ragged the trajectory tail is.
+        On the feedback path its regression target is ``RenderStats.
+        plan_signatures`` -- each signature compiled once, every chunk
+        sharing a signature reusing that program.
         """
-        from repro.core import ask as ask_lib
-
-        if self.engine == "ask_pooled":
-            from repro.core import pooled as pooled_lib
-
-            n_dev = int(self.mesh.devices.size)
-            if self.estimator is not None:
-                # the frames-per-program S is baked into the pooled
-                # pipeline build, so signatures are keyed on (key, pad,
-                # caps) -- no dedup across pad widths here
-                total = 0
-                for key, pad, caps in self._used_sigs:
-                    fn = pooled_lib._jitted_pooled(
-                        self._problems[key], caps, pad // n_dev,
-                        mesh=self.mesh)
-                    size = getattr(fn, "_cache_size", None)
-                    if not callable(size):
-                        return None
-                    total += int(size())
-                return total
-            S = self.chunk_frames // n_dev
-            caps = pooled_lib._resolve_pooled_capacities(
-                self.problem, S, self.engine_kw.get("capacities"), None,
-                self.engine_kw.get("p_subdiv", 0.7),
-                self.engine_kw.get("safety_factor", 2.0))
-            fn = pooled_lib._jitted_pooled(self.problem, caps, S,
-                                           mesh=self.mesh)
+        total = 0
+        for fn in self._programs.values():
             size = getattr(fn, "_cache_size", None)
-            return int(size()) if callable(size) else None
-        if self.estimator is not None:
-            total = 0
-            for key, caps in {(sig[0], sig[2]) for sig in self._used_sigs}:
-                fn = ask_lib._jitted_pipeline(self._problems[key], caps,
-                                              batched=True, mesh=self.mesh)
-                size = getattr(fn, "_cache_size", None)
-                if not callable(size):
-                    return None
-                total += int(size())
-            return total
-        caps = ask_lib._resolve_capacities(
-            self.problem, self.engine_kw.get("capacities"),
-            self.engine_kw.get("p_subdiv", 0.7),
-            self.engine_kw.get("safety_factor", 2.0))
-        fn = ask_lib._jitted_pipeline(self.problem, caps, batched=True,
-                                      mesh=self.mesh)
-        size = getattr(fn, "_cache_size", None)
-        return int(size()) if callable(size) else None
+            if not callable(size):
+                return None
+            total += int(size())
+        return total
 
     def render(self, bounds_seq: Iterable, *, sink=None):
         """Render a whole (finite) trajectory.

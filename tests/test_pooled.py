@@ -4,6 +4,8 @@ sizing, per-frame overflow attribution + retry, the planner integration
 (plan_pooled / solve_pooled), EngineOptions routing, sharded dead-frame
 padding, and pooled render-service chunking."""
 
+import types
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,7 +13,7 @@ import pytest
 from repro.core import pooled
 from repro.core.ask import run_ask_scan_batch
 from repro.core.planner import (BucketPlan, CapacityPlan, plan_frames,
-                                plan_pooled, solve_pooled,
+                                plan_pooled, retry_until_fit, solve_pooled,
                                 worst_case_capacities)
 from repro.launch.mesh import make_frames_mesh
 from repro.mandelbrot import MandelbrotProblem
@@ -157,30 +159,36 @@ def test_pooled_capacity_resolution_and_validation():
 
 
 def test_escalate_pooled_capacities():
-    worst = (16, 64, 256)
-    caps = (4, 10, 40)
+    """Later retries of the one pooled rule double each level."""
+    prob = MandelbrotProblem(n=256, g=4, r=2, B=16, max_dwell=64)
+    assert worst_case_capacities(prob) == (16, 64, 256)
+
+    def later(caps, ran, nxt):
+        return pooled.pooled_retry_capacities(
+            prob, caps, [((16, 64), 256)], retry=1, ran_per_shard=ran,
+            next_per_shard=nxt)
+
     # doubling, clamped at the S-frame pooled worst case
-    assert pooled.escalate_pooled_capacities(caps, worst, 1, [0]) == \
-        (8, 20, 80)
-    assert pooled.escalate_pooled_capacities((10, 60, 250), worst, 1, [0]) \
-        == (16, 64, 256)
+    assert later((4, 10, 40), 1, 1) == (8, 20, 80)
+    assert later((10, 60, 250), 1, 1) == (16, 64, 256)
     # reaching the ceiling with frames still dropping is a bug, not a
     # sizing problem
     with pytest.raises(RuntimeError, match="worst-case"):
-        pooled.escalate_pooled_capacities((16, 64, 256), worst, 1, [0, 1])
+        later((16, 64, 256), 1, 1)
     # a bigger pool raises the ceiling
-    assert pooled.escalate_pooled_capacities((16, 64, 256), worst, 2,
-                                             [0]) == (32, 128, 512)
+    assert later((16, 64, 256), 2, 2) == (32, 128, 512)
     # THE shrinking-pool regression: a frame that overflowed while
     # SHARING a 3-frame ring is not at its OWN worst case even when the
     # shared caps exceed it -- no raise, and the retry caps clamp DOWN
     # to the 1-frame ceiling (the pool shrank with them)
-    assert pooled.escalate_pooled_capacities(
-        (32, 128, 512), worst, 1, [3],
-        dispatched_per_shard=3) == (16, 64, 256)
+    assert later((32, 128, 512), 3, 1) == (16, 64, 256)
     with pytest.raises(RuntimeError, match="worst-case"):
-        pooled.escalate_pooled_capacities((48, 192, 768), worst, 1, [3],
-                                          dispatched_per_shard=3)
+        later((48, 192, 768), 3, 1)
+    # the same inputs on a FIRST retry size from the measured chain
+    # instead: 2 x (16, 64, 256), clamped at the 1-frame ceiling
+    assert pooled.pooled_retry_capacities(
+        prob, (4, 10, 40), [((16, 64), 256)], retry=0, ran_per_shard=1,
+        next_per_shard=1) == (16, 64, 256)
 
 
 # ---------------------------------------------------------------------------
@@ -606,8 +614,7 @@ class TestFailedPoolRetry:
         dispatch, and the result is bit-identical with zero drops."""
         import dataclasses as dc
 
-        from repro.core.planner import (plan_pooled, solve_pooled,
-                                        worst_case_capacities)
+        from repro.core.planner import plan_pooled, solve_pooled
 
         prob, bounds = self._mixed_batch()
         base = plan_pooled(prob, bounds, safety_factor=1.0)
@@ -623,9 +630,8 @@ class TestFailedPoolRetry:
         # the blunt whole-pool doubling would have undersized the leaf
         # level for the dense frames' TRUE need and burned a THIRD
         # dispatch; the measured sizing covered it in one
-        worst = worst_case_capacities(prob)
-        blunt = pooled.escalate_pooled_capacities(
-            caps, worst, 2, [0, 1], dispatched_per_shard=4)
+        blunt = pooled.pooled_retry_capacities(
+            prob, caps, [], retry=1, ran_per_shard=4, next_per_shard=2)
         true_leaf = ref_st.frame_leaf_counts[0] + ref_st.frame_leaf_counts[1]
         assert blunt[-1] < true_leaf
         retry_caps = rep.bucket_stats[1].olt_caps
@@ -633,9 +639,9 @@ class TestFailedPoolRetry:
 
     def test_failed_pool_capacities_sizes_from_failed_frames_only(self):
         prob = MandelbrotProblem(n=256, g=4, r=2, B=16, max_dwell=64)
-        caps = pooled.failed_pool_capacities(
-            prob, [(16, 44), (16, 64)], leaf_counts=[148, 252],
-            frames_per_shard=2)
+        caps = pooled.pooled_retry_capacities(
+            prob, (1, 1, 1), [((16, 44), 148), ((16, 64), 252)], retry=0,
+            ran_per_shard=2, next_per_shard=2)
         # 2x the measured contribution, clamped at the retry pool's own
         # worst case -- independent of how big the failed pool was
         worst = [(4 * 2 ** lv) ** 2 for lv in range(3)]
@@ -647,6 +653,83 @@ class TestFailedPoolRetry:
         worst = [(4 * 2 ** lv) ** 2 for lv in range(2)]
         full = tuple(2 * w for w in worst)  # covered 2 frames' worst case
         with pytest.raises(RuntimeError, match="worst-case"):
-            pooled.failed_pool_capacities(
-                prob, [(16,), (16,)], leaf_counts=[1, 1],
-                frames_per_shard=2, caps_prev=full, dispatched_per_shard=2)
+            pooled.pooled_retry_capacities(
+                prob, full, [((16,), 1), ((16,), 1)], retry=0,
+                ran_per_shard=2, next_per_shard=2)
+
+
+# ---------------------------------------------------------------------------
+# the one retry loop (planner.retry_until_fit) on a scripted dispatch
+# ---------------------------------------------------------------------------
+
+class TestRetryLoop:
+    """``retry_until_fit`` driven by a scripted dispatch: no program
+    runs. Frame ``i`` fits once ``caps[0] >= need[i]``; its canvas row
+    records the capacity it fit at."""
+
+    @staticmethod
+    def _script(need):
+        calls = []
+
+        def dispatch(idx, caps):
+            calls.append((list(idx), tuple(caps)))
+            over = tuple(int(need[i] > caps[0]) for i in idx)
+            states = np.asarray([[i, caps[0]] for i in idx], np.int32)
+            return states, types.SimpleNamespace(
+                frame_overflow=over, olt_caps=tuple(caps),
+                region_counts=tuple((i,) for i in idx),
+                frame_leaf_counts=tuple(10 * i for i in idx))
+
+        return dispatch, calls
+
+    @staticmethod
+    def _double(caps, tag, failed, chains, ran):
+        return tuple(2 * c for c in caps), tag + 1
+
+    def test_frames_merge_back_in_input_order(self):
+        dispatch, calls = self._script([1, 5, 2, 7, 3])
+        done = retry_until_fit(dispatch, [((4,), range(5), 0)], self._double,
+                               frames=5, rings=lambda f: f)
+        assert calls == [([0, 1, 2, 3, 4], (4,)), ([1, 3], (8,))]
+        assert done.states.tolist() == [[0, 4], [1, 8], [2, 4], [3, 8],
+                                        [4, 4]]
+        assert done.chains == tuple(((i,), 10 * i) for i in range(5))
+        assert done.tags == (0, 1, 0, 1, 0)
+        assert done.retried_frames == (1, 3)
+        assert (done.dispatches, done.retries) == (2, 2)
+        assert done.ring_rows == 5 * 2 * 4 + 2 * 2 * 8
+
+    def test_two_failed_groups_with_one_target_share_one_dispatch(self):
+        dispatch, calls = self._script([3, 1, 5, 1])
+
+        def to_eight(caps, tag, failed, chains, ran):
+            return (8,), tag
+
+        done = retry_until_fit(
+            dispatch, [((3,), [2, 3], "b"), ((2,), [0, 1], "a")], to_eight,
+            frames=4, rings=lambda f: 1)
+        # ascending ring width; frame 0 waits at (8,) for frame 2
+        assert calls == [([0, 1], (2,)), ([2, 3], (3,)), ([0, 2], (8,))]
+        assert done.states[:, 1].tolist() == [8, 2, 8, 3]
+        # frame 2 joined the pending group, so it carries that group's tag
+        assert done.tags == ("a", "a", "a", "b")
+        assert done.retries == 2
+
+    def test_returned_caps_are_the_largest_any_frame_ran_at(self):
+        dispatch, calls = self._script([1, 12])
+        first = dispatch([0, 1], (2,))
+        calls.clear()
+        done = retry_until_fit(dispatch, [((2,), [0, 1], 0)], self._double,
+                               frames=2, rings=lambda f: 1, done=first)
+        # the first group's result was handed in, not dispatched again
+        assert calls == [([1], (4,)), ([1], (8,)), ([1], (16,))]
+        assert done.caps == (16,)
+        assert done.caps == max([(2,)] + [c for _, c in calls])
+        assert done.dispatches == 4 and done.retries == 3
+
+    def test_max_dispatches_raises(self):
+        dispatch, calls = self._script([100])
+        with pytest.raises(RuntimeError, match="max_dispatches=3"):
+            retry_until_fit(dispatch, [((1,), [0], 0)], self._double,
+                            frames=1, rings=lambda f: 1, max_dispatches=3)
+        assert len(calls) == 3
