@@ -144,6 +144,11 @@ class ASKStats:
     # whose entry is nonzero gets re-planned into a larger bucket.
     frame_overflow: tuple = ()
     frame_leaf_counts: tuple = ()
+    # pooled engine only: the leaf ring rows A ran, one entry a pool
+    # (device shard). A runs block by block up to the live leaf count
+    # (``ops.region_dwell_pooled``), so over ``olt_caps[-1]`` this is
+    # the share of the leaf ring A computed.
+    dwell_rows: tuple = ()
 
     @property
     def ring_rows(self) -> int:

@@ -190,7 +190,8 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
     OLT ring of frame-tagged rows.
 
     Returns ``pipeline(bounds_all [F, 4], live [F] bool) -> (states
-    [F, n, n], entering [levels, F], leaf_f [F], frame_dropped [F])``.
+    [F, n, n], entering [levels, F], leaf_f [F], frame_dropped [F],
+    dwell_rows)``, the last the leaf ring rows A ran (int32).
     The problem must implement ``pooled_level_step`` /
     ``pooled_leaf_step`` (``workloads.FrameProblem`` does).
     """
@@ -226,9 +227,8 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
         # bookkeeping: roots, the level scan's control, children, ring
         # reads and writes, per-frame counts
         with jax.named_scope("ask.subdivide"):
-            state, entering, leaf_f, frame_dropped = level_scan(
-                state, bounds_all, live)
-        return state.reshape(F, n, n), entering, leaf_f, frame_dropped
+            state, *counters = level_scan(state, bounds_all, live)
+        return (state.reshape(F, n, n), *counters)
 
     def level_scan(state, bounds_all, live):
         # frame-major root worklist: frame f's g^2 roots, in root order,
@@ -298,9 +298,9 @@ def _build_pooled_pipeline(problem, caps: Sequence[int], frames: int):
         rows = olt_lib.ring_read(ring, parity, cap_leaf)
         valid = jnp.arange(cap_leaf) < count
         leaf_f = frame_sum(rows, valid)
-        state = problem.pooled_leaf_step(state, rows, valid, level=levels,
-                                         bounds_all=bounds_all)
-        return state, entering, leaf_f, frame_dropped
+        state, dwell_rows = problem.pooled_leaf_step(
+            state, rows, valid, level=levels, bounds_all=bounds_all)
+        return state, entering, leaf_f, frame_dropped, dwell_rows
 
     return pipeline
 
@@ -354,9 +354,11 @@ def _jitted_pooled(problem, caps: Tuple[int, ...], frames: int, mesh=None):
     return fn
 
 
-def _pooled_stats(caps, entering_fl, leaf_f, frame_dropped, wall_s) -> ASKStats:
+def _pooled_stats(caps, entering_fl, leaf_f, frame_dropped, dwell_rows,
+                  wall_s) -> ASKStats:
     """Assemble per-frame ASKStats from pooled pipeline outputs.
-    ``entering_fl`` is host-side [F, levels]."""
+    ``entering_fl`` is host-side [F, levels]; ``dwell_rows`` one entry
+    a pool (device shard)."""
     per_frame = _per_frame_counts(entering_fl)
     leaf_host = [int(c) for c in leaf_f]
     drop_host = [int(d) for d in frame_dropped]
@@ -370,6 +372,7 @@ def _pooled_stats(caps, entering_fl, leaf_f, frame_dropped, wall_s) -> ASKStats:
         olt_caps=tuple(caps),  # SHARED ring: ring_rows == the pool total
         frame_overflow=tuple(drop_host),
         frame_leaf_counts=tuple(leaf_host),
+        dwell_rows=tuple(int(x) for x in np.reshape(dwell_rows, -1)),
     )
 
 
@@ -411,12 +414,14 @@ def run_ask_pooled_batch(
                 else jnp.asarray(live, bool))
 
     t0 = time.perf_counter()
-    states, entering, leaf_f, frame_dropped = fn(bounds_all, live_arr)
+    states, entering, leaf_f, frame_dropped, dwell_rows = fn(bounds_all,
+                                                             live_arr)
     if block_until_ready:
         states = jax.block_until_ready(states)
     stats = _pooled_stats(caps, jax.device_get(entering).T,
                           jax.device_get(leaf_f),
                           jax.device_get(frame_dropped),
+                          jax.device_get(dwell_rows),
                           time.perf_counter() - t0)
     return states, stats
 
@@ -452,8 +457,8 @@ class PooledDispatch:
     (device d owns frames d*S .. (d+1)*S - 1): ``states`` is the
     program's own [F_pad, n, n] canvas output, already in input order;
     the counters carry a leading shard axis (entering [n_dev, levels,
-    S], leaf/dropped [n_dev, S]). ``caps`` is the PER-SHARD shared ring
-    sizing.
+    S], leaf/dropped [n_dev, S], dwell_rows [n_dev]). ``caps`` is the
+    PER-SHARD shared ring sizing.
 
     ``finalize()`` queues no device work: it returns ``states`` as they
     are, padded tail included (rows ``frames`` on are dead frames), and
@@ -466,6 +471,7 @@ class PooledDispatch:
     entering: Any
     leaf_f: Any
     frame_dropped: Any
+    dwell_rows: Any
     frames: int  # true F before padding
     caps: Tuple[int, ...]
     n_dev: int
@@ -489,6 +495,7 @@ class PooledDispatch:
         leaf_f = jax.device_get(self.leaf_f).reshape(-1)[:F]
         dropped = jax.device_get(self.frame_dropped).reshape(-1)[:F]
         stats = _pooled_stats(self.caps, entering, leaf_f, dropped,
+                              jax.device_get(self.dwell_rows),
                               time.perf_counter() - self.t0)
         return self.states, stats
 
@@ -552,11 +559,12 @@ def dispatch_ask_pooled_sharded(
 
     fn = _jitted_pooled(problem, caps, S, mesh=mesh)
     t0 = time.perf_counter()
-    states, entering, leaf_f, frame_dropped = fn(
+    states, entering, leaf_f, frame_dropped, dwell_rows = fn(
         bounds_all.reshape(n_dev, S, 4), live.reshape(n_dev, S))
     return PooledDispatch(states=states, entering=entering, leaf_f=leaf_f,
-                          frame_dropped=frame_dropped, frames=F,
-                          caps=tuple(caps), n_dev=n_dev, t0=t0, program=fn)
+                          frame_dropped=frame_dropped, dwell_rows=dwell_rows,
+                          frames=F, caps=tuple(caps), n_dev=n_dev, t0=t0,
+                          program=fn)
 
 
 def run_ask_pooled_sharded(

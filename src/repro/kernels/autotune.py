@@ -476,13 +476,10 @@ def _build_runner(kernel: str, impl: str, params: Dict[str, Any], *,
         u = params.get("unroll", 1)
         if impl == "jnp":
             def run():
-                tiles = ref.region_interior_dyn(
-                    rows[:, 1:], side=side, n=n,
-                    bounds=ops.pooled_bounds(bounds_all, rows),
-                    max_dwell=max_dwell, workload=workload, unroll=u)
-                ops._pooled_scatter(
-                    canvas, rows, tiles, ne,
-                    side=side, n=n).block_until_ready()
+                ops._pooled_dwell_blocks(
+                    canvas, rows, N, side=side, n=n, bounds_all=bounds_all,
+                    max_dwell=max_dwell, workload=workload,
+                    unroll=u)[0].block_until_ready()
         else:
             from repro.kernels.region_dwell_pooled import region_dwell_pooled
 

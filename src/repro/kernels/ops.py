@@ -157,7 +157,8 @@ def region_dwell(canvas, coords, nonempty, *, side, n,
             coords, side=side, n=n, bounds=bounds, max_dwell=max_dwell,
             workload=workload, unroll=unroll)
         return _scatter_tiles(canvas, coords[:, 0] * side,
-                              coords[:, 1] * side, tiles, nonempty)
+                              coords[:, 1] * side, tiles,
+                              nonempty.reshape(()) > 0)
     return _region_dwell_pallas(
         canvas, coords, nonempty, side=side, n=n, bounds=bounds,
         max_dwell=max_dwell, scheme=scheme, tile=tile,
@@ -176,15 +177,16 @@ def pooled_bounds(bounds_all, rows):
     return jnp.moveaxis(bounds_all[rows[:, 0]], -1, 0)[:, :, None, None]
 
 
-def _scatter_tiles(canvas, y0, x0, tiles, nonempty):
+def _scatter_tiles(canvas, y0, x0, tiles, keep):
     """Write [N, side, side] ``tiles`` with their top-left pixels at
     (y0, x0): ONE scatter whose update windows are whole tiles, so the
     index array is [N, 2] rather than one index per pixel (at n = 4096
-    the per-pixel form took the TPU compiler ~35 s per call site). An
-    empty OLT (``nonempty == 0``) pushes every origin past the canvas,
-    and out-of-range windows are dropped whole. Duplicate padding rows
-    rewrite identical values, so the result is order-independent."""
-    y0 = jnp.where(nonempty.reshape(()) > 0, y0, canvas.shape[0])
+    the per-pixel form took the TPU compiler ~35 s per call site).
+    Where ``keep`` (a bool scalar, or one a row) is false the origin is
+    pushed past the canvas, and out-of-range windows are dropped whole.
+    Duplicate padding rows rewrite identical values, so the result is
+    order-independent."""
+    y0 = jnp.where(keep, y0, canvas.shape[0])
     dnums = jax.lax.ScatterDimensionNumbers(
         update_window_dims=(1, 2), inserted_window_dims=(),
         scatter_dims_to_operand_dims=(0, 1))
@@ -220,12 +222,63 @@ def _pooled_fill(canvas, rows, values, nonempty, *, side, n):
                         rows[:, 2], values, nonempty, side=side)
 
 
-def _pooled_scatter(canvas, rows, tiles, nonempty, *, side, n):
+def _pooled_scatter(canvas, rows, tiles, count, *, side, n):
     """Scatter per-row [side, side] tiles onto the tall pooled canvas
     [F*n, n] at row offset frame*n -- frames are disjoint bands, so ONE
-    scatter serves the whole pool, as in the per-frame jnp lowering."""
+    scatter serves the whole pool, as in the per-frame jnp lowering.
+    Rows at or past ``count`` are dropped, one by one: their tiles hold
+    nothing, and their origins may repeat a live row's."""
+    live = jnp.arange(rows.shape[0]) < count
     return _scatter_tiles(canvas, rows[:, 0] * n + rows[:, 1] * side,
-                          rows[:, 2] * side, tiles, nonempty)
+                          rows[:, 2] * side, tiles, live)
+
+
+# Pixels leaf dwell A evaluates in one block of the pooled block loop: at
+# side 32, 2048 ring rows, whose escape-loop planes (3 x 8 MiB) the TPU
+# compiler keeps in VMEM. On a v5e the n = 4096, 4-frame chunk program
+# ran 0.888 s with 2048-row blocks, 0.905 s with 4096 and 0.909 s with
+# 8192 (2.162 s over the whole 65,536-row ring)
+DWELL_BLOCK_PIXELS = 1 << 21
+
+
+def dwell_block_rows(side: int, cap: int) -> int:
+    """Ring rows a block of the pooled jnp dwell: the largest power of
+    two whose tiles hold at most ``DWELL_BLOCK_PIXELS`` pixels (at least
+    one row), clamped to the ring's ``cap`` rows."""
+    per = max(1, DWELL_BLOCK_PIXELS // (side * side))
+    return max(1, min(1 << (per.bit_length() - 1), cap))
+
+
+def _pooled_dwell_blocks(canvas, rows, count, *, side, n, bounds_all,
+                         max_dwell, workload, unroll):
+    """The jnp pooled A over the live rows only: a loop over the
+    ``ceil(count / R)`` blocks of ``R = dwell_block_rows(side, cap)``
+    rows that hold live rows, each block's tiles written into a
+    [cap, side, side] buffer, then one scatter of the live rows. Each
+    pixel sees the ops ``region_interior_dyn`` gives it over the whole
+    ring, so the canvas is bit-identical. Where ``R`` does not divide
+    ``cap``, the last block's slice is clamped to end at ``cap``
+    (``dynamic_slice``'s rule) and rewrites rows the block before it
+    wrote, with the same values. Returns the canvas and the ring rows A
+    ran, ``ceil(count / R) * R`` (int32)."""
+    cap = rows.shape[0]
+    R = dwell_block_rows(side, cap)
+    count = jnp.asarray(count, jnp.int32).reshape(())
+    blocks = (count + (R - 1)) // R
+
+    def block(b, tiles):
+        start = b * R
+        blk = jax.lax.dynamic_slice_in_dim(rows, start, R)
+        vals = ref.region_interior_dyn(
+            blk[:, 1:], side=side, n=n, bounds=pooled_bounds(bounds_all, blk),
+            max_dwell=max_dwell, workload=workload, unroll=unroll)
+        return jax.lax.dynamic_update_slice_in_dim(
+            tiles, vals.astype(canvas.dtype), start, axis=0)
+
+    tiles = jax.lax.fori_loop(
+        0, blocks, block, jnp.zeros((cap, side, side), canvas.dtype))
+    return (_pooled_scatter(canvas, rows, tiles, count, side=side, n=n),
+            blocks * R)
 
 
 def region_fill_pooled(canvas, rows, values, nonempty, *, side, n,
@@ -248,32 +301,37 @@ def region_fill_pooled(canvas, rows, values, nonempty, *, side, n,
         interpret=pol.resolve_interpret())
 
 
-def region_dwell_pooled(canvas, rows, nonempty, *, side, n, bounds_all,
+def region_dwell_pooled(canvas, rows, count, *, side, n, bounds_all,
                         max_dwell=512, backend=None, policy=None,
                         workload=None):
     """Pooled last-level work A: interior values of frame-tagged leaves.
 
-    Each row's interior is evaluated in its own frame's window: the jnp
-    lowering broadcasts ``pooled_bounds``'s [4, N, 1, 1] components
-    against the per-row planes; the Pallas lowering
-    (``kernels.region_dwell_pooled``) stages the [F, 4] windows through
-    scalar prefetch and lands each tile in its frame band directly --
-    bit-identical per pixel, so the tuned tier picks freely."""
+    ``rows`` [cap, 3] holds ``count`` (int32 scalar) live rows first;
+    what follows them is never written. Each row's interior is evaluated
+    in its own frame's window: the jnp lowering broadcasts
+    ``pooled_bounds``'s [4, N, 1, 1] components against the per-row
+    planes, block by block up to ``count`` (``_pooled_dwell_blocks``);
+    the Pallas lowering (``kernels.region_dwell_pooled``) stages the
+    [F, 4] windows through scalar prefetch and lands each tile in its
+    frame band directly, over every row, the dead ones duplicating row
+    0 -- bit-identical per pixel, so the tuned tier picks freely.
+    Returns the canvas and the ring rows A ran (int32)."""
     pol = resolve_policy(backend, policy)
     F = canvas.shape[0] // n
     impl, params = _route(pol, "region_dwell_pooled", workload=workload,
                           side=side, n=n, F=F, max_dwell=max_dwell)
     unroll = int(params.get("unroll", 1))
     if impl == "jnp":
-        tiles = ref.region_interior_dyn(
-            rows[:, 1:], side=side, n=n,
-            bounds=pooled_bounds(bounds_all, rows),
+        return _pooled_dwell_blocks(
+            canvas, rows, count, side=side, n=n, bounds_all=bounds_all,
             max_dwell=max_dwell, workload=workload, unroll=unroll)
-        return _pooled_scatter(canvas, rows, tiles, nonempty, side=side, n=n)
+    cap = rows.shape[0]
+    idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
+    nonempty = (count > 0).astype(jnp.int32).reshape((1,))
     return _region_dwell_pooled_pallas(
-        canvas, rows, nonempty, bounds_all, side=side, n=n, F=F,
+        canvas, rows[idx], nonempty, bounds_all, side=side, n=n, F=F,
         max_dwell=max_dwell, interpret=pol.resolve_interpret(),
-        workload=workload, unroll=unroll)
+        workload=workload, unroll=unroll), jnp.int32(cap)
 
 
 def compact_ranks(flags, *, backend=None, policy=None):

@@ -160,7 +160,10 @@ class ChunkStats:
     ``[d * S, (d + 1) * S)`` of the padded batch (frame-major, ``S`` =
     padded width over devices). Each is one entry a device, counting the
     live leaf regions (``ASKStats.frame_leaf_counts``) and the live
-    frames there; padding frames count on neither.
+    frames there; padding frames count on neither. ``shard_dwell_rows``
+    (pooled engine) is the leaf ring rows leaf dwell A ran on each
+    device in the chunk's own dispatch (``ASKStats.dwell_rows``); over
+    the leaf ring's rows it is the share of the ring A computed.
     """
 
     index: int
@@ -192,6 +195,7 @@ class ChunkStats:
     observe_s: float = 0.0
     shard_leaf_counts: tuple = ()
     shard_frames: tuple = ()
+    shard_dwell_rows: tuple = ()
 
     @property
     def busy_s(self) -> float:
@@ -593,7 +597,8 @@ class RenderService:
             fetch_s=fetch_s, in_flight=in_flight, workload=item.key,
             tenants=tuple(tenants), plan_s=item.plan_s,
             wait_s=wait.seconds, stats_s=stats.seconds,
-            shard_leaf_counts=leaves, shard_frames=frames))
+            shard_leaf_counts=leaves, shard_frames=frames,
+            shard_dwell_rows=st.dwell_rows))
 
     def _pad_width(self, f: int) -> int:
         """Padding width of a feedback-path dispatch: the next power-of-
@@ -779,6 +784,7 @@ class RenderService:
             olt_caps=done.caps,  # == caps when nothing retried
             frame_overflow=(0,) * f,
             frame_leaf_counts=tuple(leaf for _, leaf in chains),
+            dwell_rows=st.dwell_rows,  # the chunk's own dispatch
         )
         retry_s = retry.seconds if retry else 0.0
         return done.states, merged, done.retries, done.ring_rows, retry_s
@@ -829,7 +835,8 @@ class RenderService:
             ring_rows=ring, workload=key, tenants=tuple(tenants),
             plan_s=item.plan_s, wait_s=wait.seconds, stats_s=stats.seconds,
             copy_s=copy.seconds, retry_s=retry_s, observe_s=observe.seconds,
-            shard_leaf_counts=leaves, shard_frames=frames))
+            shard_leaf_counts=leaves, shard_frames=frames,
+            shard_dwell_rows=merged.dwell_rows))
 
     # -- multi-tenant front-door seam ---------------------------------------
 

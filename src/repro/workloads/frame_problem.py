@@ -249,15 +249,14 @@ class FrameProblem:
 
     def pooled_leaf_step(self, state: jax.Array, rows: jax.Array,
                          valid: jax.Array, *, level: int,
-                         bounds_all) -> jax.Array:
+                         bounds_all) -> Tuple[jax.Array, jax.Array]:
+        """A over the live leaf rows (``valid``, a prefix of the ring):
+        ``(state, rows A ran)``."""
         side = self.region_side(level)
         with jax.named_scope("ask.dwell"):
-            cap = rows.shape[0]
             count = jnp.sum(valid.astype(jnp.int32))
-            idx = jnp.where(jnp.arange(cap) < count, jnp.arange(cap), 0)
-            nonempty = (count > 0).astype(jnp.int32).reshape((1,))
             return ops.region_dwell_pooled(
-                state, rows[idx], nonempty, side=side, n=self.n,
+                state, rows, count, side=side, n=self.n,
                 bounds_all=bounds_all, max_dwell=self.max_dwell,
                 policy=self.policy, workload=self.workload)
 

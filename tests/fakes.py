@@ -101,6 +101,7 @@ class FakeStats:
     region_counts: tuple = ()
     frame_overflow: tuple = ()
     frame_leaf_counts: tuple = ()
+    dwell_rows: tuple = ()
 
     def frame_chains(self) -> tuple:
         return tuple(zip(self.region_counts, self.frame_leaf_counts))

@@ -22,6 +22,7 @@ from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 HBM_BYTES = 16 * 10**9  # one v5e chip (Google Cloud, "TPU v5e")
 N, FRAMES = 4096, 8
+LEAF_CAP = 44059  # the leaf ring ``pooled_capacities`` plans at P = 0.7
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +88,7 @@ def pooled_compiled(problem, frames_mesh):
     from repro.core.pooled import _jitted_pooled, pooled_capacities
 
     caps = pooled_capacities(problem, (0.7,) * FRAMES)
+    assert caps[-1] == LEAF_CAP
     fn = _jitted_pooled(problem, caps, FRAMES, mesh=frames_mesh)
     return fn.lower(
         jax.ShapeDtypeStruct((1, FRAMES, 4), jnp.float32),
@@ -101,13 +103,14 @@ def test_pooled_chunk_program_compiles(pooled_compiled):
 
 def test_pooled_escape_loop_carries_lane_dense_planes(pooled_compiled,
                                                       problem):
-    """Leaf dwell A's escape loop (the ``while`` under ``ask.dwell``)
-    streams its carry through HBM on every trip. Planes of
-    ``[rows, 32, 32]`` tile as (8, 128) and pad each to four times its
-    bytes; the loop must carry them 128 lanes wide."""
+    """Leaf dwell A's escape loop (the ``while`` in the body of the
+    block loop under ``ask.dwell``) streams its carry through memory on
+    every trip. Planes of ``[rows, 32, 32]`` tile as (8, 128) and pad
+    each to four times its bytes; the loop must carry them 128 lanes
+    wide."""
     loops = [line for line in pooled_compiled.as_text().splitlines()
              if re.search(r"= \(.*\) while\(.*op_name=\"[^\"]*ask\.dwell/"
-                          r"while\"", line)]
+                          r"while/body/while\"", line)]
     assert len(loops) == 1, loops
     carry = loops[0].split(" while(")[0]
     arrays = [(dtype, [int(d) for d in dims.split(",")])
@@ -120,6 +123,27 @@ def test_pooled_escape_loop_carries_lane_dense_planes(pooled_compiled,
     # z's real and imaginary parts and the dwell, each over every leaf pixel
     held = [dtype for dtype, dims in planes if int(np.prod(dims)) == pixels]
     assert held.count("f32") >= 2 and held.count("s32") >= 1, carry
+
+
+def test_pooled_dwell_loops_over_live_blocks(pooled_compiled, problem):
+    """A runs block by block up to the leaf count: the ``while`` under
+    ``ask.dwell`` stops at a bound it carries (the block count, read
+    from the live leaf count at run time), not at a constant, and the
+    escape loop in its body covers ``dwell_block_rows`` rows a trip."""
+    from repro.kernels import ops
+
+    text = pooled_compiled.as_text()
+    outer = re.search(r"= \(.*\) while\(.*condition=(%[\w.]+),.*"
+                      r"op_name=\"[^\"]*ask\.dwell/while\"", text)
+    assert outer, "no block loop under ask.dwell"
+    cond = re.search(r"^" + re.escape(outer.group(1)) + r" .*?^}", text,
+                     re.M | re.S).group(0)
+    assert "compare(" in cond and "constant(" not in cond, cond
+    inner = re.search(r"= \((.*)\) while\(.*op_name=\"[^\"]*ask\.dwell/"
+                      r"while/body/while\"", text)
+    rows = ops.dwell_block_rows(problem.B, LEAF_CAP)
+    assert rows < LEAF_CAP
+    assert f"[{rows},8,128]" in inner.group(1), inner.group(1)
 
 
 def test_ask_scan_batch_program_compiles(problem, frames_mesh):

@@ -370,12 +370,67 @@ def test_render_service_pooled_sharded():
     assert "OK" in out
 
 
+def test_pooled_shards_dwell_their_own_live_blocks():
+    """On 8 host devices each shard's A stops after the block holding
+    its own last live leaf row: shards with different live counts run
+    ``ceil(count / R) * R`` rows each (``ASKStats.dwell_rows``), their
+    canvases equal the one-device pool's, and the render service's
+    ``ChunkStats.shard_dwell_rows`` lines up with ``shard_leaf_counts``.
+    The block is cut to 8 rows so the leaf rings span several."""
+    out = _run("""
+        import numpy as np
+        from repro.core.pooled import (run_ask_pooled_batch,
+                                       run_ask_pooled_sharded)
+        from repro.kernels import ops
+        from repro.launch.mesh import make_frames_mesh
+        from repro.launch.render_service import RenderService
+        from repro.workloads import FrameProblem
+
+        R = 8
+        prob = FrameProblem(n=128, g=4, r=2, B=16, max_dwell=32)
+        ops.DWELL_BLOCK_PIXELS = R * prob.B * prob.B
+        mesh = make_frames_mesh()
+
+        def window(cx, cy, w):
+            return (cx - w / 2, cy - w / 2, cx + w / 2, cy + w / 2)
+
+        # two frames a shard, from a wide overview to a deep boundary zoom
+        b = np.stack([window(-0.7436447860, 0.1318252536, 4.0 / 1.6 ** k)
+                      for k in range(16)]).astype(np.float32)
+        one, st_one = run_ask_pooled_batch(prob, b, safety_factor=1e9)
+        shd, st = run_ask_pooled_sharded(prob, b, mesh=mesh,
+                                         safety_factor=1e9)
+        np.testing.assert_array_equal(np.asarray(shd), np.asarray(one))
+        assert st.frame_leaf_counts == st_one.frame_leaf_counts
+        leaves = [sum(st.frame_leaf_counts[2 * d:2 * d + 2])
+                  for d in range(8)]
+        assert len(set(leaves)) > 2, leaves
+        assert R < st.olt_caps[-1]
+        assert ops.dwell_block_rows(prob.B, st.olt_caps[-1]) == R
+        assert st.dwell_rows == tuple(-(-c // R) * R for c in leaves)
+        assert st_one.dwell_rows == (-(-st_one.leaf_count // R) * R,)
+
+        svc = RenderService(prob, engine="ask_pooled", mesh=mesh,
+                            chunk_frames=16, safety_factor=1e9)
+        for r in svc.stream_chunks(list(b)):
+            c = r.chunk
+            R_c = ops.dwell_block_rows(prob.B, r.stats.olt_caps[-1])
+            assert c.shard_dwell_rows == tuple(
+                -(-x // R_c) * R_c for x in c.shard_leaf_counts), c
+            np.testing.assert_array_equal(np.asarray(r.canvases),
+                                          np.asarray(one))
+        print("OK")
+    """)
+    assert "OK" in out
+
+
 # The four-chip zoom-video host (``zoom4k_x4``): pooled feedback serving
 # on a 4-device ``frames`` mesh, 4 frames a device, fed a video dealt
 # serpentine so that each chunk lists its frames in rising depth.
 _ZOOM_HOST = """
     import numpy as np
     from repro.launch.mesh import make_frames_mesh
+    from repro.kernels import ops
     from repro.launch.render_service import RenderService, zoom_bounds
     from repro.workloads import FrameProblem, exhaustive
 
@@ -405,6 +460,7 @@ _ZOOM_HOST = """
     svc, canv, rs = serve(DEVICES)
     assert svc.chunk_frames == CHUNK and rs.overflow_dropped == 0
     # the counters against the frames' own leaf counts, chunk by chunk
+    unretried = 0
     for r in svc.stream_chunks(bounds):
         c, leaves = r.chunk, r.stats.frame_leaf_counts
         assert len(c.shard_leaf_counts) == len(c.shard_frames) == DEVICES
@@ -415,6 +471,12 @@ _ZOOM_HOST = """
             mine = range(d * per, min((d + 1) * per, c.frames))
             assert c.shard_frames[d] == len(mine), (c.shard_frames, per)
             assert c.shard_leaf_counts[d] == sum(leaves[j] for j in mine)
+        if c.retries == 0:  # the dwell counter is the chunk's own dispatch
+            unretried += 1
+            R = ops.dwell_block_rows(prob.B, r.stats.olt_caps[-1])
+            assert c.shard_dwell_rows == tuple(
+                -(-x // R) * R for x in c.shard_leaf_counts), c
+    assert unretried
     if DEVICES > 1:
         _, one, _ = serve(1)
         np.testing.assert_array_equal(canv, one)

@@ -7,7 +7,9 @@ frame-tagged worklists -- including duplicate-padded tails and the
 ``nonempty = 0`` no-write guarantee. Plus the routing surface: the ops
 entry points must dispatch the Pallas lowerings for pallas/tuned policies
 (no jnp pin), and ``ask_pooled`` under a tuned policy with a pooled cache
-must stay bit-identical to the jnp engine end to end.
+must stay bit-identical to the jnp engine end to end. Last, the jnp
+dwell's loop over the live blocks of the leaf ring against A over every
+live row at once, at each edge of a block.
 """
 
 import numpy as np
@@ -136,8 +138,8 @@ def test_pooled_dwell_three_way_identity(data):
     ba = jnp.asarray(bounds_all)
     ne = jnp.ones((1,), jnp.int32)
 
-    jnp_out = ops.region_dwell_pooled(
-        canvas, rows, ne, side=side, n=n, bounds_all=ba,
+    jnp_out, _ = ops.region_dwell_pooled(
+        canvas, rows, jnp.int32(live), side=side, n=n, bounds_all=ba,
         max_dwell=MAX_DWELL, backend="jnp")
     pallas_out = pallas_dwell_pooled(
         canvas, rows, ne, ba, side=side, n=n, F=F, max_dwell=MAX_DWELL,
@@ -168,9 +170,9 @@ def test_pooled_kernels_nonempty_zero_no_write():
                                backend="jnp"),
         pallas_fill_pooled(canvas, rows, values, ne0, side=side, n=n, F=F,
                            interpret=True),
-        ops.region_dwell_pooled(canvas, rows, ne0, side=side, n=n,
-                                bounds_all=ba, max_dwell=MAX_DWELL,
-                                backend="jnp"),
+        ops.region_dwell_pooled(canvas, rows, jnp.int32(0), side=side,
+                                n=n, bounds_all=ba, max_dwell=MAX_DWELL,
+                                backend="jnp")[0],
         pallas_dwell_pooled(canvas, rows, ne0, ba, side=side, n=n, F=F,
                             max_dwell=MAX_DWELL, interpret=True),
     ):
@@ -223,8 +225,8 @@ def test_pooled_route_pallas_policy_no_jnp_pin(monkeypatch):
     pol = KernelPolicy(backend="pallas", interpret=True)
     ops.region_fill_pooled(canvas, rows, jnp.zeros((4,), jnp.int32), ne,
                            side=side, n=n, policy=pol)
-    ops.region_dwell_pooled(canvas, rows, ne, side=side, n=n, bounds_all=ba,
-                            max_dwell=8, policy=pol)
+    ops.region_dwell_pooled(canvas, rows, jnp.int32(4), side=side, n=n,
+                            bounds_all=ba, max_dwell=8, policy=pol)
     assert seen == ["fill", "dwell"]
 
 
@@ -253,12 +255,13 @@ def test_pooled_tuned_cache_routes_pallas(tmp_path):
         rng.integers(0, F, 6), rng.integers(0, n // side, 6),
         rng.integers(0, n // side, 6)], axis=1).astype(np.int32))
     canvas = jnp.asarray(rng.integers(0, 5, (F * n, n)).astype(np.int32))
-    ne = jnp.ones((1,), jnp.int32)
     ba = jnp.asarray(np.asarray(_WINDOWS[:F], np.float32))
-    got = ops.region_dwell_pooled(canvas, rows, ne, side=side, n=n,
-                                  bounds_all=ba, max_dwell=8, policy=pol)
-    want = ops.region_dwell_pooled(canvas, rows, ne, side=side, n=n,
-                                   bounds_all=ba, max_dwell=8, backend="jnp")
+    count = jnp.int32(6)
+    got, _ = ops.region_dwell_pooled(canvas, rows, count, side=side, n=n,
+                                     bounds_all=ba, max_dwell=8, policy=pol)
+    want, _ = ops.region_dwell_pooled(canvas, rows, count, side=side, n=n,
+                                      bounds_all=ba, max_dwell=8,
+                                      backend="jnp")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -313,3 +316,74 @@ def test_ask_pooled_tuned_matches_jnp_end_to_end(tmp_path, workload):
                                             safety_factor=1e9)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert st_p.kernel_launches == 1
+
+
+# ---------------------------------------------------------------------------
+# the jnp lowering runs A only over the live blocks of the leaf ring
+
+_BLOCK_CAP, _BLOCK_ROWS = 14, 4  # the last block's slice is clamped
+
+
+@pytest.mark.parametrize("count", [0, 1, _BLOCK_ROWS - 1, _BLOCK_ROWS,
+                                   _BLOCK_ROWS + 1, _BLOCK_CAP - 1,
+                                   _BLOCK_CAP])
+@pytest.mark.parametrize("workload_name",
+                         ["mandelbrot", "julia", "burning_ship", "ssd_synth"])
+def test_pooled_dwell_live_blocks_bit_identical(monkeypatch, workload_name,
+                                                count):
+    """The block loop of ``ops.region_dwell_pooled`` against A over all
+    live rows at once, then a scatter: equal bit for bit at every edge
+    of a block, for every workload kind. The rows past ``count`` hold
+    random origins, live regions among them, so any of them written
+    would change a live region's pixels or one outside them."""
+    from repro.workloads import get_workload
+
+    F, side, n = 3, 8, 32
+    regions = n // side
+    monkeypatch.setattr(ops, "DWELL_BLOCK_PIXELS",
+                        _BLOCK_ROWS * side * side)
+    assert ops.dwell_block_rows(side, _BLOCK_CAP) == _BLOCK_ROWS
+    workload = get_workload(workload_name)
+    rng = np.random.default_rng(count * 7 + len(workload_name))
+    cells = np.stack(np.meshgrid(np.arange(F), np.arange(regions),
+                                 np.arange(regions), indexing="ij"),
+                     axis=-1).reshape(-1, 3)
+    live = cells[rng.permutation(len(cells))[:count]]
+    garbage = np.stack([rng.integers(0, F, _BLOCK_CAP - count),
+                        rng.integers(0, regions, _BLOCK_CAP - count),
+                        rng.integers(0, regions, _BLOCK_CAP - count)],
+                       axis=1)
+    rows = np.concatenate([live, garbage]).astype(np.int32)
+    re0, im0, re1, im1 = workload.default_bounds
+    bounds_all = np.asarray(
+        [(re0 + (re1 - re0) * s, im0 + (im1 - im0) * s,
+          re1 - (re1 - re0) * s, im1 - (im1 - im0) * s)
+         for s in (0.0, 0.2, 0.35)], np.float32)
+    canvas = rng.integers(0, 7, size=(F * n, n)).astype(np.int32)
+
+    got, dwell_rows = ops.region_dwell_pooled(
+        jnp.asarray(canvas), jnp.asarray(rows), jnp.int32(count),
+        side=side, n=n, bounds_all=jnp.asarray(bounds_all),
+        max_dwell=MAX_DWELL, policy=KernelPolicy(backend="jnp"),
+        workload=workload)
+    want = canvas.copy()
+    if count:
+        tiles = np.asarray(ref.region_interior_dyn(
+            jnp.asarray(live[:, 1:], jnp.int32), side=side, n=n,
+            bounds=ops.pooled_bounds(jnp.asarray(bounds_all),
+                                     jnp.asarray(live, jnp.int32)),
+            max_dwell=MAX_DWELL, workload=workload))
+        for (f, cy, cx), tile in zip(live, tiles):
+            y0, x0 = f * n + cy * side, cx * side
+            want[y0:y0 + side, x0:x0 + side] = tile
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert int(dwell_rows) == -(-count // _BLOCK_ROWS) * _BLOCK_ROWS
+
+
+@pytest.mark.parametrize("side,cap,rows", [
+    (32, 65536, 2048), (32, 1024, 1024), (16, 65536, 8192),
+    (8, 300, 300), (48, 8192, 512), (4096, 16, 1)])
+def test_dwell_block_rows_from_shape(side, cap, rows):
+    """``dwell_block_rows``: the power of two of at most 2^21 pixels a
+    block, clamped to the ring, at least one row."""
+    assert ops.dwell_block_rows(side, cap) == rows
